@@ -8,6 +8,8 @@ from __future__ import annotations
 
 import json
 import os
+import subprocess
+import sys
 import time
 
 import numpy as np
@@ -38,6 +40,21 @@ PAPER_TABLE5 = {
                    16: (0.32, 0.30), 32: (0.64, 0.62), 64: (1.28, 1.26)},
 }
 
+
+
+def _run_cpu_child(script: str, what: str) -> str:
+    """Run a fake-device bench script in a child pinned to the CPU (the
+    parent may hold the accelerator, which one process owns at a time)
+    and return its stdout; a failed child fails the run."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, "-c", script], env=env,
+                       capture_output=True, text=True, timeout=900)
+    if r.returncode != 0:
+        raise RuntimeError(f"{what} subprocess failed (rc {r.returncode}):"
+                           f"\n{r.stderr[-1500:]}")
+    return r.stdout
 
 def _family(label: str):
     from repro.matrices import Exciton, Hubbard, RoadNet, SpinChainXXZ, TopIns
@@ -271,9 +288,6 @@ def spmv_overlap():
     overlap-aware perf-model prediction T = max(T_comm, T_local) + T_halo
     (CPU host threads can't hide the exchange — the measured columns are a
     correctness+overhead check; the model columns are the hardware story)."""
-    import subprocess
-    import sys
-
     rows = []
     print("\n=== Overlap SpMV vs baseline (8 fake devices, panel 4x2) ===")
     script = """
@@ -311,18 +325,10 @@ err = np.abs(ys["overlap"] - ys["baseline"]).max()
 assert err < 1e-11, err
 print(f"HALO_FRAC {ell.halo_nnz_fraction:.4f}")
 """
-    env = dict(os.environ, PYTHONPATH=os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", script], env=env,
-                       capture_output=True, text=True, timeout=900)
-    if r.returncode != 0:
-        print(f"overlap bench subprocess failed:\n{r.stderr[-1500:]}")
-        rows.append(("spmv_overlap", 0.0, "status=fail"))
-        return rows
+    stdout = _run_cpu_child(script, "overlap bench")
     meas = {}
     halo_frac = 0.0
-    for line in r.stdout.splitlines():
+    for line in stdout.splitlines():
         if line.startswith("ROW "):
             _, name, us = line.split()
             meas[name] = float(us)
@@ -412,27 +418,17 @@ print("AGREE OK")
 
 def _measure_spmv_engines(ctor: str, engines, table: str, label: str):
     """Run :data:`_ENGINE_BENCH_SCRIPT` for one matrix-ctor string and
-    return ``{engine_name: (us_per_call, measured_bytes)}``, or ``None``
-    on subprocess failure (already printed). The ctor string is the
+    return ``{engine_name: (us_per_call, measured_bytes)}``; a failed
+    subprocess raises (:func:`_run_cpu_child`). The ctor string is the
     single source of truth for the instance: it is pasted into the
     measuring subprocess AND evaluated by the caller for the host-side
     prediction, so the two sides can never diverge."""
-    import subprocess
-    import sys
-
-    env = dict(os.environ, PYTHONPATH=os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
-    env.pop("XLA_FLAGS", None)
     script = _ENGINE_BENCH_SCRIPT.format(family=ctor,
                                          engines=repr(list(engines)))
-    r = subprocess.run([sys.executable, "-c", script], env=env,
-                       capture_output=True, text=True, timeout=900)
-    if r.returncode != 0:
-        print(f"{table} subprocess failed for {label}:\n{r.stderr[-1500:]}")
-        return None
-    assert "AGREE OK" in r.stdout
+    stdout = _run_cpu_child(script, f"{table} bench for {label}")
+    assert "AGREE OK" in stdout
     out = {}
-    for line in r.stdout.splitlines():
+    for line in stdout.splitlines():
         if line.startswith("ROW "):
             _, name, us, meas = line.split()
             out[name] = (float(us), int(meas))
@@ -473,9 +469,6 @@ def spmv_comm():
         pred = {"a2a": cp.a2a_bytes_per_device(4, 8),
                 "compressed": cp.permute_bytes_per_device(4, 8)}
         meas_by_eng = _measure_spmv_engines(ctor, engines, "spmv_comm", label)
-        if meas_by_eng is None:
-            rows.append((f"spmv_comm_{label}", 0.0, "status=fail"))
-            continue
         for name, (us, meas) in meas_by_eng.items():
             p = pred["compressed" if name.startswith("cmp") else "a2a"]
             assert meas == p, (label, name, meas, p)
@@ -558,9 +551,6 @@ def spmv_schedule():
             n_rounds[name] = len(cp.permute_schedule(sched)[0])
         meas_by_eng = _measure_spmv_engines(ctor, engines, "spmv_schedule",
                                             label)
-        if meas_by_eng is None:
-            rows.append((f"spmv_schedule_{label}", 0.0, "status=fail"))
-            continue
         for name, (us, meas) in meas_by_eng.items():
             p = pred[name]
             assert meas == p, (label, name, meas, p)
@@ -661,17 +651,11 @@ def kernels_table():
     print("\n=== SpMV kernel axis (8 fake devices, panel 4x2, cmp+ov+mat) ===")
     print(f"{'family':10s} {'kernel':10s} {'pred B/dev':>11s} "
           f"{'meas B/dev':>11s} {'us/call':>9s}")
-    import subprocess
-    import sys
-
     from repro.core.metrics import chi_metrics
     from repro.core.planner import comm_plan
     from repro.matrices import RoadNet, SpinChainXXZ
 
     ctors = {"RoadNet": RoadNet, "SpinChainXXZ": SpinChainXXZ}
-    env = dict(os.environ, PYTHONPATH=os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
-    env.pop("XLA_FLAGS", None)
     for label, ctor in fams:
         mat = eval(ctor, {"__builtins__": {}}, ctors)
         D_pad = -(-mat.D // 8) * 8
@@ -680,15 +664,9 @@ def kernels_table():
         pred = cp.permute_bytes_per_device(4, 8, "matching")
         script = _KERNEL_BENCH_SCRIPT.format(family=ctor,
                                              cells=repr(cells))
-        r = subprocess.run([sys.executable, "-c", script], env=env,
-                           capture_output=True, text=True, timeout=900)
-        if r.returncode != 0:
-            print(f"kernels subprocess failed for {label}:\n"
-                  f"{r.stderr[-1500:]}")
-            rows.append((f"kernels_{label}", 0.0, "status=fail"))
-            continue
-        assert "AGREE OK" in r.stdout
-        for line in r.stdout.splitlines():
+        stdout = _run_cpu_child(script, f"kernels bench for {label}")
+        assert "AGREE OK" in stdout
+        for line in stdout.splitlines():
             if not line.startswith("ROW "):
                 continue
             _, name, us, meas = line.split()
@@ -785,9 +763,6 @@ def sstep_table():
     subprocess asserts all depths bit-identical (``np.array_equal``).
     Every row lands in :data:`RECORDS` with the ``s`` field of
     ``schema.SSTEP_VALUES`` for the ``run.py --json`` artifact."""
-    import subprocess
-    import sys
-
     rows = []
     degree = 8
     fams = [("spinchain", "SpinChainXXZ(12, 6)"),
@@ -803,9 +778,6 @@ def sstep_table():
     from repro.matrices import HubNet, SpinChainXXZ
 
     ctors = {"HubNet": HubNet, "SpinChainXXZ": SpinChainXXZ}
-    env = dict(os.environ, PYTHONPATH=os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
-    env.pop("XLA_FLAGS", None)
     n_b, S_d = 8 // 2, 8
     for label, ctor in fams:
         mat = eval(ctor, {"__builtins__": {}}, ctors)
@@ -826,15 +798,10 @@ def sstep_table():
                 wf[s] = cp.sstep_work_factor()
             script = _SSTEP_BENCH_SCRIPT.format(family=ctor, comm=comm,
                                                 sched=sched, degree=degree)
-            r = subprocess.run([sys.executable, "-c", script], env=env,
-                               capture_output=True, text=True, timeout=900)
-            if r.returncode != 0:
-                print(f"sstep subprocess failed for {label}/{eng}:\n"
-                      f"{r.stderr[-1500:]}")
-                rows.append((f"sstep_{label}_{eng}", 0.0, "status=fail"))
-                continue
-            assert "SSTEP AGREE OK" in r.stdout
-            for line in r.stdout.splitlines():
+            stdout = _run_cpu_child(script,
+                                    f"sstep bench for {label}/{eng}")
+            assert "SSTEP AGREE OK" in stdout
+            for line in stdout.splitlines():
                 if not line.startswith("ROW "):
                     continue
                 _, s, us, meas = line.split()
@@ -927,9 +894,6 @@ def partition_table():
     stay bit-identical on it, and that the un-permuted result equals the
     reference SpMV. Every row lands in :data:`RECORDS` for the
     ``run.py --json`` trajectory artifact."""
-    import subprocess
-    import sys
-
     rows = []
     fams = [("spinchain", "SpinChainXXZ(12, 6)"),
             ("roadnet", "RoadNet(n=4000, w=2, m=256, k=4)"),
@@ -946,9 +910,6 @@ def partition_table():
 
     ctors = {"HubNet": HubNet, "RoadNet": RoadNet,
              "SpinChainXXZ": SpinChainXXZ}
-    env = dict(os.environ, PYTHONPATH=os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
-    env.pop("XLA_FLAGS", None)
     for label, ctor in fams:
         mat = eval(ctor, {"__builtins__": {}}, ctors)
         pred, chis, blocks = {}, {}, {}
@@ -963,16 +924,10 @@ def partition_table():
             blocks[tag] = f"{int(sizes.min())}..{int(sizes.max())}"
         script = _PARTITION_BENCH_SCRIPT.format(family=ctor,
                                                 cells=repr(cells))
-        r = subprocess.run([sys.executable, "-c", script], env=env,
-                           capture_output=True, text=True, timeout=900)
-        if r.returncode != 0:
-            print(f"partition subprocess failed for {label}:\n"
-                  f"{r.stderr[-1500:]}")
-            rows.append((f"partition_{label}", 0.0, "status=fail"))
-            continue
-        assert "PARTITION AGREE OK" in r.stdout
+        stdout = _run_cpu_child(script, f"partition bench for {label}")
+        assert "PARTITION AGREE OK" in stdout
         meas = {}
-        for line in r.stdout.splitlines():
+        for line in stdout.splitlines():
             if line.startswith("ROW "):
                 _, tag, eng, us, m = line.split()
                 meas[(tag, eng)] = (float(us), int(m))

@@ -8,14 +8,15 @@ iterations, SpMVs, redistribution count/time, and the per-SpMV collective
 bytes measured from the compiled HLO (which follow the χ metric exactly).
 The eigenvalues agree across layouts and with dense eigh.
 
-This script re-executes itself with 8 fake XLA devices.
+This script re-executes itself with 8 fake XLA devices on the CPU.
 """
 import os
 import subprocess
 import sys
 
 if "XLA_FLAGS" not in os.environ:
-    env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_count=8")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=8")
     sys.exit(subprocess.run([sys.executable] + sys.argv, env=env).returncode)
 
 import jax

@@ -39,7 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 
-from jax import core as jax_core
+from jax.extend import core as jax_core
 import jax
 
 __all__ = ["OverlapReport", "PipelineReport", "check_split_phase",

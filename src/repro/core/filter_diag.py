@@ -38,8 +38,8 @@ with the other engine axes.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
-from typing import Callable
 
 import numpy as np
 import jax
@@ -128,6 +128,17 @@ class FDState:
     result: FDResult | None = None
 
 
+def _jit_full_precision(f):
+    """jit ``f`` traced with full-precision matmuls. A TPU runs float32
+    dots in bfloat16 passes by default, which leaves Rayleigh-Ritz
+    residuals near 1e-3; CPU dots are full precision either way."""
+    def traced(*args):
+        with jax.default_matmul_precision("highest"):
+            return f(*args)
+
+    return jax.jit(traced)
+
+
 class FilterDiag:
     """Filter diagonalization on a (row x col) solver mesh.
 
@@ -140,6 +151,18 @@ class FilterDiag:
     def __init__(self, matrix, mesh: Mesh, cfg: FDConfig,
                  panel_layout: Layout | None = None,
                  rowmap=None):
+        dt = jnp.dtype(cfg.dtype)
+        if getattr(matrix, "is_complex", False) and not jnp.issubdtype(dt, jnp.complexfloating):
+            dt = jnp.dtype("complex128" if dt == jnp.float64 else "complex64")
+        on_tpu = mesh.devices.flat[0].platform == "tpu"
+        if dt == jnp.complex128 and on_tpu:
+            # the TPU compiler aborts the process on a complex128 matmul
+            # and refuses complex128 qr/eigh/vdot: refuse before any trace
+            raise ValueError(
+                "complex128 does not compile for TPU; solve complex "
+                "families there with FDConfig(dtype='float32'), which runs "
+                "them in complex64")
+        self.dtype = dt
         if panel_layout is None and cfg.layout == "auto":
             # the planner decides spmv_overlap — work on a copy so the
             # caller's config object is not mutated
@@ -160,13 +183,17 @@ class FilterDiag:
         self.N_col = self.panel_layout.n_col(mesh)
         if cfg.n_search % max(self.N_col, 1):
             raise ValueError("n_search must be divisible by N_col")
+        if on_tpu and cfg.ortho == "tsqr" and dt == jnp.float64 \
+                and self.P_total > 1:
+            # XLA:TPU rejects a float64 QR inside shard_map across chips
+            raise ValueError(
+                "float64 TSQR does not compile for a multi-chip TPU mesh "
+                "(\"A tuple parameter that is being flattened shouldn't "
+                "have frontend attributes\"); use FDConfig(ortho='svqb') "
+                "there")
         if cfg.spmv_sstep < 1:
             raise ValueError(f"spmv_sstep must be >= 1 "
                              f"(got {cfg.spmv_sstep})")
-        dt = jnp.dtype(cfg.dtype)
-        if getattr(matrix, "is_complex", False) and not jnp.issubdtype(dt, jnp.complexfloating):
-            dt = jnp.dtype("complex128" if dt == jnp.float64 else "complex64")
-        self.dtype = dt
         D = matrix.shape[0] if hasattr(matrix, "shape") else matrix.D
         self.D = D
         # planned row decomposition (core/partition.py): the auto planner
@@ -290,9 +317,11 @@ class FilterDiag:
         )
         if cfg.ortho == "tsqr":
             self._tsqr = make_tsqr(mesh, self.stack_layout)
-            self.orthogonalize = jax.jit(lambda V: self._tsqr(V)[0])
+            self.orthogonalize = _jit_full_precision(
+                lambda V: self._tsqr(V)[0])
         else:
-            self.orthogonalize = jax.jit(make_svqb(mesh, self.stack_layout))
+            self.orthogonalize = _jit_full_precision(
+                make_svqb(mesh, self.stack_layout))
         self.gram = make_gram(mesh, self.stack_layout)
         self.to_panel, self.to_stack = make_redistribute(
             mesh, self.stack_layout, self.panel_layout, impl=cfg.redist_impl
@@ -300,8 +329,8 @@ class FilterDiag:
         self.to_panel = jax.jit(self.to_panel)
         self.to_stack = jax.jit(self.to_stack)
 
-        def ritz(V):
-            AV = self.spmv_stack(V)
+        def ritz(spmv, V):
+            AV = spmv(V)
             H = self.gram(V, AV)  # [Ns, Ns] replicated
             H = 0.5 * (H + jnp.conj(H.T))
             theta, Y = jnp.linalg.eigh(H)
@@ -312,23 +341,21 @@ class FilterDiag:
             res = jnp.sqrt(jnp.sum(jnp.abs(Rm) ** 2, axis=0))
             return theta, Y, res, VY
 
-        self.ritz = jax.jit(ritz)
-        self._cheb_cache: dict[int, Callable] = {}
+        # the engines are passed in, not captured, so their operator
+        # arrays are program parameters rather than baked-in constants
+        self.ritz = functools.partial(_jit_full_precision(ritz),
+                                      self.spmv_stack)
+        # filter(V, mu, alpha, beta): one compiled program per degree, the
+        # static length of mu
+        if self.cheb_sstep is not None:
+            self.filter = jax.jit(self.cheb_sstep)
+        else:
+            def run(spmv, fused_step, V, mu, alpha, beta):
+                return chebyshev_filter(spmv, mu, alpha, beta, V,
+                                        fused_step=fused_step)
 
-    def _cheb(self, degree: int):
-        if degree not in self._cheb_cache:
-            if self.cheb_sstep is not None:
-                run = self.cheb_sstep
-            else:
-                spmv = self.spmv_panel
-                fused_step = self.fused_step_panel
-
-                def run(V, mu, alpha, beta):
-                    return chebyshev_filter(spmv, mu, alpha, beta, V,
-                                            fused_step=fused_step)
-
-            self._cheb_cache[degree] = jax.jit(run)
-        return self._cheb_cache[degree]
+            self.filter = functools.partial(
+                jax.jit(run), self.spmv_panel, self.fused_step_panel)
 
     # ------------------------------------------------------------------
     def random_search_vectors(self, key) -> jax.Array:
@@ -505,7 +532,7 @@ class FilterDiag:
             jax.block_until_ready(V)
             state.redistributions += 1
             state.redist_time += time.perf_counter() - t0
-        V = self._cheb(degree)(V, mu, alpha, beta)
+        V = self.filter(V, mu, alpha, beta)
         state.total_spmvs += degree * cfg.n_search
         t0 = time.perf_counter()
         if self.N_col > 1:

@@ -13,6 +13,18 @@ import jax.numpy as jnp
 __all__ = ["lanczos_interval"]
 
 
+@jax.jit
+def _lanczos_step(spmv, v, v_prev, beta):
+    """One three-term step as one program (one host sync per step).
+    ``spmv`` is an engine pytree (``core/spmv.py``), passed in so its
+    operator arrays are parameters, not constants."""
+    w = spmv(v)
+    with jax.default_matmul_precision("highest"):  # TPU f32: no bf16 passes
+        a = jnp.real(jnp.vdot(v, w))
+    w = w - a * v - beta * v_prev
+    return w, a, jnp.linalg.norm(w)
+
+
 def lanczos_interval(spmv, D: int, D_pad: int, dtype, key, steps: int = 30,
                      safety: float = 1.05, mask=None):
     """Return (lambda_l, lambda_r) enclosing spec(A).
@@ -37,10 +49,8 @@ def lanczos_interval(spmv, D: int, D_pad: int, dtype, key, steps: int = 30,
     v_prev = jnp.zeros_like(v)
     beta = 0.0
     for k in range(steps):
-        w = spmv(v)
-        a = float(jnp.real(jnp.vdot(v, w)))
-        w = w - a * v - beta * v_prev
-        b = float(jnp.linalg.norm(w))
+        w, a, b = _lanczos_step(spmv, v, v_prev, beta)
+        a, b = float(a), float(b)
         alphas.append(a)
         betas.append(b)
         if b < 1e-12:

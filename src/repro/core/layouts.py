@@ -43,7 +43,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["Layout", "stack", "pillar", "panel", "make_solver_mesh", "SOLVER_ROW", "SOLVER_COL"]
 
@@ -53,11 +53,16 @@ SOLVER_COL = "col"  # vertical layer (N_s)
 
 def make_solver_mesh(n_row: int, n_col: int, *, pods: int = 1, devices=None) -> Mesh:
     """Eigensolver mesh. With pods > 1 the pod axis multiplies the vertical
-    layer (bundles of vectors across pods — zero SpMV communication)."""
+    layer (bundles of vectors across pods — zero SpMV communication).
+
+    Axes are ``Auto``: the engines place their own collectives inside
+    ``shard_map`` and leave the rest to GSPMD propagation."""
     if pods > 1:
-        return jax.make_mesh((pods, n_row, n_col), ("pod", SOLVER_ROW, SOLVER_COL),
-                             devices=devices)
-    return jax.make_mesh((n_row, n_col), (SOLVER_ROW, SOLVER_COL), devices=devices)
+        shape, axes = (pods, n_row, n_col), ("pod", SOLVER_ROW, SOLVER_COL)
+    else:
+        shape, axes = (n_row, n_col), (SOLVER_ROW, SOLVER_COL)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -16,7 +16,7 @@ import math
 
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .layouts import Layout
@@ -80,7 +80,7 @@ def make_tsqr(mesh: Mesh, layout: Layout):
         mesh=mesh,
         in_specs=(vec_spec,),
         out_specs=(vec_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn
 
@@ -95,7 +95,7 @@ def make_gram(mesh: Mesh, layout: Layout):
         return lax.psum(g, dist) if dist else g
 
     return shard_map(local_fn, mesh=mesh, in_specs=(vec_spec, vec_spec),
-                     out_specs=P(), check_rep=False)
+                     out_specs=P(), check_vma=False)
 
 
 def make_svqb(mesh: Mesh, layout: Layout, eps: float = 1e-14):

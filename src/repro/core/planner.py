@@ -688,7 +688,9 @@ def plan_layout(matrix, n_devices: int, *, n_search: int,
     (``perf_model.fused_kernel_machine``) — the wire bytes are
     unchanged, only the memory-traffic term improves. The axis defaults
     to off (``(False,)``); pass ``kernel=(False, True)`` to let the
-    ranking decide (``--spmv-kernel`` with ``--layout auto`` does).
+    ranking decide (``--spmv-kernel`` with ``--layout auto`` does). On
+    TPU, where the tile kernel does not compile, asking for the axis
+    raises :class:`~repro.kernels.ops.KernelRefused`.
 
     ``sstep`` widens the grid with the **seventh axis** — the s-step
     ghost-zone depth of the communication-avoiding filter
@@ -761,6 +763,10 @@ def plan_layout(matrix, n_devices: int, *, n_search: int,
     if plan_mode not in PLAN_MODES:
         raise ValueError(f"unknown plan_mode {plan_mode!r} "
                          f"(expected one of {PLAN_MODES})")
+    if any(kernel):
+        from ..kernels.ops import require_tile_kernel
+
+        require_tile_kernel()
     plan_ok = partition_plan_default(matrix, P)
     use_sampled = plan_mode == "sampled" or (plan_mode == "auto"
                                              and not plan_ok)

@@ -42,7 +42,7 @@ TPU — the tiled all_to_all already produces the canonical layout.
 from __future__ import annotations
 
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh
 
 from .layouts import Layout
@@ -96,7 +96,7 @@ def make_redistribute(mesh: Mesh, stack_layout: Layout, panel_layout: Layout,
         return lax.all_to_all(Vb, col_axes, split_axis=0, concat_axis=1, tiled=True)
 
     to_panel = shard_map(_to_panel_local, mesh=mesh, in_specs=(stack_spec,),
-                         out_specs=panel_spec, check_rep=False)
+                         out_specs=panel_spec, check_vma=False)
     to_stack = shard_map(_to_stack_local, mesh=mesh, in_specs=(panel_spec,),
-                         out_specs=stack_spec, check_rep=False)
+                         out_specs=stack_spec, check_vma=False)
     return to_panel, to_stack

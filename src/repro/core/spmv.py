@@ -99,9 +99,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..kernels.ref import cheb_epilogue
 from ..matrices.families import MatrixFamily
 from ..matrices.sparse import CSR
 from .layouts import Layout
@@ -802,7 +803,7 @@ def _contract_block(acc, cols, vals, xsrc, tiles):
 
     tile_cb, tcols, tvals, br, bc = tiles
     return kops.ell_spmv_tiled(tile_cb, tcols, tvals, xsrc, y0=acc,
-                               br=br, bc=bc, cols=cols, vals=vals)
+                               br=br, bc=bc)
 
 
 def _local_spmv(cols, vals, send_idx, x, dist_axes, P_row, L, tiles=None):
@@ -1015,16 +1016,10 @@ def _build_engine(mesh: Mesh, layout: Layout, ell: DistEll, *, use_kernel: bool,
                 mesh=mesh,
                 in_specs=(pspec(dia.dvals), vec_spec, vec_spec, P(), P()),
                 out_specs=vec_spec,
-                check_rep=False,
+                check_vma=False,
             )
 
-            def step_dia(w1, w2, alpha, beta):
-                rdt = jnp.zeros((), dtype=w1.dtype).real.dtype
-                a = jnp.asarray(alpha, dtype=rdt)
-                b = jnp.asarray(beta, dtype=rdt)
-                return fn(dia.dvals, w1, w2, a, b)
-
-            return step_dia
+            return _bind(_fused_caller(fn), [dia.dvals], "cheb_dia")
 
     if comm == "compressed":
         nbr = ell.neighbor_plan(split_halo=overlap, schedule=schedule)
@@ -1088,29 +1083,24 @@ def _build_engine(mesh: Mesh, layout: Layout, ell: DistEll, *, use_kernel: bool,
 
     n_args = len(args)
     plan_specs = tuple(pspec(a) for a in args)
+    body_name = "ell_gather" if tiles_plan is not None else "jnp"
 
     if fused:
         def local_fn_fused(*ins):
             dev = [a[0] for a in ins[:n_args]]
             w1, w2, a, b = ins[n_args:]
             y = body(w1, *dev)
-            return 2.0 * a * y + 2.0 * b * w1 - w2
+            return cheb_epilogue(y, w1, w2, a, b)
 
         fn = shard_map(
             local_fn_fused,
             mesh=mesh,
             in_specs=plan_specs + (vec_spec, vec_spec, P(), P()),
             out_specs=vec_spec,
-            check_rep=False,
+            check_vma=False,
         )
 
-        def step(w1, w2, alpha, beta):
-            rdt = jnp.zeros((), dtype=w1.dtype).real.dtype  # complex-safe
-            a = jnp.asarray(alpha, dtype=rdt)
-            b = jnp.asarray(beta, dtype=rdt)
-            return fn(*args, w1, w2, a, b)
-
-        return step
+        return _bind(_fused_caller(fn), args, body_name)
 
     def local_fn(*ins):
         dev = [a[0] for a in ins[:n_args]]
@@ -1122,13 +1112,35 @@ def _build_engine(mesh: Mesh, layout: Layout, ell: DistEll, *, use_kernel: bool,
         mesh=mesh,
         in_specs=plan_specs + (vec_spec,),
         out_specs=vec_spec,
-        check_rep=False,
+        check_vma=False,
     )
 
-    def spmv(x):
-        return fn(*args, x)
+    return _bind(fn, args, body_name)
 
-    return spmv
+
+def _fused_caller(fn):
+    """``fn(*plan, w1, w2, a, b)`` with alpha/beta cast to the vectors'
+    real dtype (complex-safe)."""
+    def step(*ins):
+        *plan, w1, w2, alpha, beta = ins
+        rdt = jnp.zeros((), dtype=w1.dtype).real.dtype
+        return fn(*plan, w1, w2, jnp.asarray(alpha, dtype=rdt),
+                  jnp.asarray(beta, dtype=rdt))
+
+    return step
+
+
+def _bind(fn, args, body: str):
+    """The engine as a pytree: ``fn`` (jitted) bound to its operator
+    arrays, which are the leaves. A jitted caller that takes the engine
+    as an argument receives those arrays as parameters; captured in a
+    closure they would be baked into every program as constants (hundreds
+    of MB at deployment size). Called eagerly, the engine runs one cached
+    program instead of re-tracing its shard_map body's scans every call.
+    ``body`` names the local contraction dispatched."""
+    engine = jax.tree_util.Partial(jax.jit(fn), *args)
+    engine.body = body
+    return engine
 
 
 def make_spmv(mesh: Mesh, layout: Layout, ell: DistEll, *,
@@ -1160,7 +1172,13 @@ def make_spmv(mesh: Mesh, layout: Layout, ell: DistEll, *,
     flight; ``pipeline=False`` keeps the all-rounds-then-contract body
     (the negative control of the split-phase round proof). All twelve
     engine combinations ({a2a, cmp-cyclic, cmp-matching} x {plain,
-    overlap} x {kernel off, on}) agree bit-for-bit."""
+    overlap} x {kernel off, on}) agree bit-for-bit.
+
+    The returned function's ``body`` attribute names the local
+    contraction it dispatches (``"ell_gather"``, ``"cheb_dia"`` for the
+    fused step, or ``"jnp"``). On TPU a requested kernel that cannot take
+    the operator raises :class:`~repro.kernels.ops.KernelRefused` here,
+    at build time; it is never replaced by the jnp body."""
     return _build_engine(mesh, layout, ell, use_kernel=use_kernel,
                          overlap=overlap, comm=comm, schedule=schedule,
                          pipeline=pipeline, fused=False)
@@ -1736,7 +1754,7 @@ def _build_sstep_group(mesh: Mesh, layout: Layout, sell: SstepEll, *,
         mesh=mesh,
         in_specs=plan_specs + vec_in + (P(), P()),
         out_specs=(stk_spec, vec_spec, vec_spec),
-        check_rep=False,
+        check_vma=False,
     )
 
     if first:

@@ -17,15 +17,27 @@ traffic factor at κ=5; on TPU we additionally re-think the *format*:
     constant along that axis, so the block is revisited consecutively).
     The x operand is passed twice with diagonal-dependent index maps
     (aligned blocks k and k+1) so an unaligned offset is assembled from
-    two aligned VMEM tiles with one dynamic sublane slice — no HBM gather
-    exists on the critical path.
+    two aligned VMEM tiles with one dynamic sublane rotate
+    (``pltpu.roll``: Mosaic lowers no ``dynamic_slice`` of a value) — no
+    HBM gather exists on the critical path.
 
-  * The fused epilogue 2a*(A x) + 2b*w1 - w2 runs on the last diagonal,
-    so W2 is read exactly once from HBM (κ = 5, not 6 — paper §3.2).
+  * The diagonal values arrive transposed, as one [BR, n_diag] block per
+    row block: the full diagonal axis is the lane dim, so the block
+    meets Mosaic's (8, 128)-or-full-dim rule at any diagonal count. The
+    grid step's diagonal is picked with a one-hot lane select and a lane
+    sum, which is exact (every other lane adds 0).
+
+  * The kernel computes A x only. The three-term epilogue 2a*(A x) +
+    2b*w1 - w2 is :func:`ref.cheb_epilogue`, applied by the caller in the
+    same expression the jnp engines use: inside the kernel it was
+    contracted into an FMA in interpret mode while the engines' epilogue
+    is not, and the kernel-on and kernel-off engines must agree bit for
+    bit. The price on TPU is one more pass over a vector block (the
+    kernel writes A x, the epilogue reads it back).
 
 Block sizes: BR rows (multiple of 8 sublanes) x BN vector columns
-(multiple of 128 lanes). VMEM footprint/step ≈ (2 x-tiles + w1 + w2 + out
-+ slice temp) * BR * BN * 4B ≈ 6 * 512 * 256 * 4B ≈ 3.1 MiB « 16 MiB.
+(multiple of 128 lanes). VMEM footprint/step ≈ (2 x-tiles + out + rotate
+temp) * BR * BN * 4B + BR * 128 * 4B ≈ 2.3 MiB « 16 MiB at 512 x 256.
 
 Complex matrices are handled in ops.py by splitting into real/imag DIA
 planes (TPU has no native complex VREG type); this kernel is real-only.
@@ -37,95 +49,90 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-specific grid spec (scalar prefetch); absent on some backends
-    from jax.experimental.pallas import tpu as pltpu
-
-    _GRID_SPEC = pltpu.PrefetchScalarGridSpec
-except Exception:  # pragma: no cover
-    _GRID_SPEC = None
+from .ref import cheb_epilogue
 
 DEFAULT_BR = 512
 DEFAULT_BN = 256
 
 
-def _kernel(off_blk, off_in, ab, dvals, x0, x1, w1, w2, out, *, n_diag, br):
+def _kernel(off_blk, rot, dvals, x0, x1, out):
     d = pl.program_id(2)
+    br = out.shape[0]
 
     @pl.when(d == 0)
     def _init():
         out[...] = jnp.zeros_like(out)
 
-    shift = off_in[d]
+    # xs = xx[shift : shift + br]: rotate left by shift, keep the head
     xx = jnp.concatenate([x0[...], x1[...]], axis=0)
-    xs = jax.lax.dynamic_slice_in_dim(xx, shift, br, axis=0)
-    dv = dvals[0, :]
-    out[...] += dv[:, None] * xs
-
-    @pl.when(d == n_diag - 1)
-    def _epilogue():
-        a2 = 2.0 * ab[0]
-        b2 = 2.0 * ab[1]
-        out[...] = a2 * out[...] + b2 * w1[...] - w2[...]
+    xs = pltpu.roll(xx, rot[d], 0)[:br]
+    dvb = dvals[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, dvb.shape, 1)
+    dv = jnp.sum(jnp.where(lane == d, dvb, 0), axis=1, keepdims=True)
+    out[...] += dv * xs
 
 
 @functools.partial(jax.jit, static_argnames=("offsets", "br", "bn", "interpret"))
-def cheb_dia(
+def dia_spmv(
     offsets: tuple[int, ...],
     dvals: jax.Array,  # [n_diag, R] per-diagonal values (0 where invalid)
     x: jax.Array,      # [Rx, nb], Rx >= R (halo may be appended)
-    w1: jax.Array,     # [R, nb]
-    w2: jax.Array,     # [R, nb]
-    alpha,
-    beta,
     br: int = DEFAULT_BR,
     bn: int = DEFAULT_BN,
     interpret: bool = False,
 ):
-    """y = 2a*(A@x) + 2b*w1 - w2 for the DIA matrix given by (offsets, dvals).
+    """A @ x [R, nb] for the DIA matrix given by (offsets, dvals).
 
     Rows where i + offset falls outside [0, Rx) must carry dvals == 0 (the
     host builder guarantees this); their x tiles are clamped loads whose
     contribution is multiplied by zero.
     """
-    n_diag = len(offsets)
-    R, nb = w1.shape
-    Rx = x.shape[0]
+    n_diag, R = dvals.shape
+    Rx, nb = x.shape
     assert R % br == 0 and nb % bn == 0, (R, nb, br, bn)
     assert Rx % br == 0
     nxb = Rx // br
     off_blk = jnp.asarray([o // br for o in offsets], jnp.int32)
-    off_in = jnp.asarray([o % br for o in offsets], jnp.int32)
-    ab = jnp.stack([jnp.asarray(alpha, dvals.dtype), jnp.asarray(beta, dvals.dtype)])
+    # left rotation by the in-block shift o % br, as jnp.roll's amount
+    rot = jnp.asarray([(2 * br - o % br) % (2 * br) for o in offsets],
+                      jnp.int32)
 
     grid = (R // br, nb // bn, n_diag)
 
     def x_map(k):  # k = 0 or 1: aligned block at floor(offset/br) + k, clamped
-        def im(rb, cb, d, off_blk_ref, off_in_ref, ab_ref):
+        def im(rb, cb, d, off_blk_ref, rot_ref):
             blk = rb + off_blk_ref[d] + k
-            blk = jnp.clip(blk, 0, nxb - 1)
+            # explicit int32 bounds: under x64 jnp.clip's weak int64
+            # bounds send Mosaic's convert lowering into a recursion
+            blk = jax.lax.clamp(jnp.int32(0), blk, jnp.int32(nxb - 1))
             return blk, cb
 
         return im
 
-    kernel = functools.partial(_kernel, n_diag=n_diag, br=br)
-    if _GRID_SPEC is not None:
-        grid_spec = _GRID_SPEC(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, br), lambda rb, cb, d, *_: (d, rb)),  # dvals
-                pl.BlockSpec((br, bn), x_map(0)),
-                pl.BlockSpec((br, bn), x_map(1)),
-                pl.BlockSpec((br, bn), lambda rb, cb, d, *_: (rb, cb)),  # w1
-                pl.BlockSpec((br, bn), lambda rb, cb, d, *_: (rb, cb)),  # w2
-            ],
-            out_specs=pl.BlockSpec((br, bn), lambda rb, cb, d, *_: (rb, cb)),
-        )
-        return pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((R, nb), w1.dtype),
-            interpret=interpret,
-        )(off_blk, off_in, ab, dvals, x, x, w1, w2)
-    raise NotImplementedError("PrefetchScalarGridSpec unavailable")
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=grid,
+        in_specs=[
+            # dvals.T; int32 zero: under x64 a Python 0 lowers as i64
+            pl.BlockSpec((br, n_diag),
+                         lambda rb, cb, d, *_: (rb, jnp.int32(0))),
+            pl.BlockSpec((br, bn), x_map(0)),
+            pl.BlockSpec((br, bn), x_map(1)),
+        ],
+        out_specs=pl.BlockSpec((br, bn), lambda rb, cb, d, *_: (rb, cb)),
+    )
+    return pl.pallas_call(
+        _kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R, nb), x.dtype),
+        interpret=interpret,
+    )(off_blk, rot, dvals.T, x, x)
+
+
+def cheb_dia(offsets, dvals, x, w1, w2, alpha, beta, br: int = DEFAULT_BR,
+             bn: int = DEFAULT_BN, interpret: bool = False):
+    """y = 2a*(A@x) + 2b*w1 - w2 for the DIA matrix given by (offsets, dvals)."""
+    ax = dia_spmv(offsets, dvals, x, br=br, bn=bn, interpret=interpret)
+    return cheb_epilogue(ax, w1, w2, alpha, beta)

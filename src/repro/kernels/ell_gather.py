@@ -8,10 +8,11 @@ row-block x col-block ELL with tile-local columns). The kernel grid is
 (row blocks, n_b blocks, tiles); each step loads one x column-block into
 VMEM and gathers rows from it with `jnp.take` along the sublane axis.
 
-Caveat recorded in DESIGN.md: Mosaic's sublane dynamic-gather support is
-newer than the rest of the ops used here; the kernel is validated in
-interpret mode on CPU (this container) and the ops.py dispatcher keeps the
-scan-of-gathers jnp path as the fallback on real hardware.
+The kernel runs in interpret mode only. It does not compile for TPU:
+Mosaic lowers neither the per-slot ``dynamic_slice`` of the tile's
+columns nor the sublane gather they feed (docs/kernels.md, "What the TPU
+compiler accepts"), so on TPU ``ops.plan_ell_tiles`` refuses it with an
+error instead of substituting the jnp scan.
 """
 from __future__ import annotations
 
@@ -21,13 +22,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _GRID_SPEC = pltpu.PrefetchScalarGridSpec
-except Exception:  # pragma: no cover
-    _GRID_SPEC = None
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BR = 256
 DEFAULT_BC = 2048  # x rows per column block resident in VMEM
@@ -156,9 +151,7 @@ def ell_gather_spmv(
     if y0 is None:
         y0 = jnp.zeros((R, nb), dtype=x.dtype)
     grid = (RB, nb // bn, T)
-    if _GRID_SPEC is None:
-        raise NotImplementedError
-    grid_spec = _GRID_SPEC(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[

@@ -1,12 +1,16 @@
 """Dispatch layer for the Pallas kernels.
 
-``prefer_pallas()`` is True only on TPU backends; on CPU (this container)
-the kernels run in ``interpret=True`` mode (or fall back to the jnp
-reference path where a block decomposition does not exist). Complex DIA
-matrices are decomposed into real/imaginary planes (4 real kernel calls)
-since TPU VREGs have no complex type; the ref-vs-kernel decision is made
-ONCE, before the decomposition, so a fallback runs one complex reference
-call instead of four real ones.
+``prefer_pallas()`` is True only on TPU backends. Off TPU the kernels
+run in ``interpret=True`` mode, and the jnp reference stands in where a
+block decomposition does not exist. On the hardware path
+(``interpret=False``) a requested kernel runs or raises
+:class:`KernelRefused`: 64-bit operands (``float64``, ``complex128``) have
+no TPU vector type and are refused before Mosaic sees them, and so are
+shapes no kernel block divides. Complex DIA matrices are decomposed into
+real/imaginary planes (4 real kernel calls) since TPU VREGs have no
+complex type; the ref-vs-kernel decision is made ONCE, before the
+decomposition, so a CPU fallback runs one complex reference call instead
+of four real ones.
 
 Two host-side planners feed the distributed engine (``core/spmv.py``):
 
@@ -15,6 +19,9 @@ Two host-side planners feed the distributed engine (``core/spmv.py``):
   build time — tiles can only be built from *concrete* host arrays, so
   the planner returns ``None`` on traced/abstract operands (e.g. the
   dryrun surrogate operator) and the engine keeps the jnp scan path.
+  The tile kernel does not compile for TPU (its in-kernel row gather has
+  no Mosaic lowering), so on TPU the planner raises
+  :class:`KernelRefused` instead.
 * :func:`plan_dia` extracts a DIA (offset, diagonal-values) form of a
   zero-halo local block for the fused ``cheb_dia`` Chebyshev kernel,
   with offsets sorted ascending so the per-row accumulation order equals
@@ -35,8 +42,13 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from .cheb_dia import cheb_dia as _cheb_dia_kernel
+from .cheb_dia import dia_spmv as _cheb_dia_kernel
 from .ell_gather import build_tiles, ell_gather_spmv
+
+#: Lane-block candidates for the vector count: the hardware path needs a
+#: multiple of 128 lanes; interpret mode accepts any divisor.
+HW_BN = (256, 128)
+INTERPRET_BN = (256, 128, 64, 32, 16, 8, 4, 2, 1)
 
 #: Row-block candidates for the tile kernel (first divisor of R wins).
 ELL_BR_CANDIDATES = (256, 128, 64, 32, 16, 8)
@@ -50,8 +62,41 @@ ELL_BC = 512
 DIA_MAX_DIAGS = 64
 
 
+#: Operand dtypes the hardware path refuses: Mosaic has no 64-bit float
+#: vector type (complex64 runs as float32 planes).
+HW_REFUSED_DTYPES = (np.dtype("float64"), np.dtype("complex128"))
+
+
+class KernelRefused(ValueError):
+    """An explicitly requested Pallas kernel cannot run these operands on
+    the TPU. Raised instead of substituting the jnp reference."""
+
+
 def prefer_pallas() -> bool:
     return jax.default_backend() == "tpu"
+
+
+#: The TPU compiler's words on the ell_gather tile kernel (v5e, JAX
+#: 0.9.0): the per-slot column slice and the sublane gather it feeds
+#: have no Mosaic lowering (docs/kernels.md).
+ELL_GATHER_TPU_REFUSAL = ("Unimplemented primitive in Pallas TPU lowering "
+                          "for KernelType.TC: dynamic_slice")
+
+
+def require_tile_kernel():
+    """Raise on TPU, where the ell_gather tile kernel does not compile."""
+    if prefer_pallas():
+        raise KernelRefused(
+            "the ell_gather tile kernel does not compile for TPU ("
+            f"{ELL_GATHER_TPU_REFUSAL}); run with spmv_kernel=False there")
+
+
+def _check_hw_dtypes(*arrays):
+    for a in arrays:
+        if np.dtype(a.dtype) in HW_REFUSED_DTYPES:
+            raise KernelRefused(
+                f"{np.dtype(a.dtype)} operand: the TPU kernels take 32-bit "
+                "operands only (use FDConfig(dtype='float32'))")
 
 
 def is_concrete(a) -> bool:
@@ -90,6 +135,7 @@ def plan_ell_tiles(cols, vals, Rx: int, *, bc: int = ELL_BC,
                    br_candidates=ELL_BR_CANDIDATES) -> EllTilePlan | None:
     """Build the ell_gather tile batch for a stacked [P, R, W] ELL block.
 
+    Raises :class:`KernelRefused` on TPU (:func:`require_tile_kernel`).
     Returns ``None`` (caller keeps the jnp scan path) when
 
     * the operands are not concrete host arrays (dryrun surrogates),
@@ -104,6 +150,7 @@ def plan_ell_tiles(cols, vals, Rx: int, *, bc: int = ELL_BC,
     compressed engines — and kernel-on == kernel-off bit-for-bit
     (padded slots add a bit-neutral ``+ 0.0``).
     """
+    require_tile_kernel()
     if not (is_concrete(cols) and is_concrete(vals)):
         return None
     cols = np.asarray(cols)
@@ -132,28 +179,24 @@ def plan_ell_tiles(cols, vals, Rx: int, *, bc: int = ELL_BC,
 
 
 def ell_spmv_tiled(tile_cb, tcols, tvals, x, y0=None, *, br: int, bc: int,
-                   cols=None, vals=None, interpret=None):
+                   interpret=None):
     """Contract a per-device tile batch against ``x``, threading ``y0``.
 
     ``tile_cb [RB, T]`` / ``tcols``/``tvals [RB, T, br, Wt]`` are one
     shard's slice of an :class:`EllTilePlan`. The vector-block size bn is
-    chosen at trace time from ``x.shape[1]``; if no kernel-compatible bn
-    exists on the real-hardware path the jnp scan runs instead (pass the
-    original ``cols``/``vals`` to enable that fallback — interpret mode
-    always has bn=1 available, so on CPU the kernel always runs).
+    chosen at trace time from ``x.shape[1]``. Interpret mode always has
+    bn=1 available; on the hardware path a vector count that no lane
+    block (256, 128) divides, or a 64-bit operand, raises
+    :class:`KernelRefused`.
     """
     interpret = (not prefer_pallas()) if interpret is None else interpret
     nb = x.shape[1]
-    bn = _pick_block(nb, (256, 128) if not interpret
-                    else (256, 128, 64, 32, 16, 8, 4, 2, 1))
-    if bn is None:
-        if cols is None or vals is None:
-            raise ValueError("no kernel-compatible bn and no fallback block")
-        acc = y0
-        if acc is None:
-            acc = jnp.zeros((cols.shape[0], nb),
-                            dtype=jnp.result_type(vals, x))
-        return ref.ell_spmv_acc_ref(acc, cols, vals, x)
+    bn = _pick_block(nb, HW_BN if not interpret else INTERPRET_BN)
+    if not interpret:
+        _check_hw_dtypes(tvals, x)
+        if bn is None:
+            raise KernelRefused(f"no lane block of {HW_BN} divides the "
+                                f"vector count nb={nb}")
     pad = (-x.shape[0]) % bc
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
@@ -243,43 +286,36 @@ def cheb_dia(offsets, dvals, x, w1, w2, alpha, beta, *, interpret=None,
              force_ref=False):
     """Fused Chebyshev DIA step with real/complex dispatch.
 
-    The kernel-vs-reference decision (``_too_small``, ragged R/nb via
-    ``_pick_block``, ``x.shape[0] % br``) is made ONCE up front; a
-    complex operand that falls back therefore runs a single complex
-    reference call, not four real-plane reference calls.
+    In interpret mode the kernel-vs-reference decision (``_too_small``,
+    ragged R via ``_pick_block``, ``x.shape[0] % br``) is made ONCE up
+    front; a complex operand that falls back therefore runs a single
+    complex reference call, not four real-plane reference calls. On the
+    hardware path those shapes, and 64-bit operands, raise
+    :class:`KernelRefused`; only ``force_ref`` selects the reference.
     """
     interpret = (not prefer_pallas()) if interpret is None else interpret
     R, nb = w1.shape
     br = _pick_block(R, (512, 256, 128, 64, 32, 16, 8))
-    bn = _pick_block(nb, (256, 128) if not interpret
-                    else (256, 128, 64, 32, 16, 8, 4, 2, 1))
-    if (force_ref or (interpret and _too_small(dvals, w1))
-            or br is None or bn is None or x.shape[0] % br):
+    bn = _pick_block(nb, HW_BN if not interpret else INTERPRET_BN)
+    ragged = br is None or bn is None or x.shape[0] % br
+    if force_ref or (interpret and (_too_small(dvals, w1) or ragged)):
         return ref.cheb_dia_ref(offsets, dvals, x, w1, w2, alpha, beta)
-    call = functools.partial(_call_real, offsets, br=br, bn=bn,
-                             interpret=interpret)
+    if not interpret:
+        _check_hw_dtypes(dvals, x, w1, w2)
+        if ragged:
+            raise KernelRefused(
+                f"no (row, lane) block of the cheb_dia kernel fits R={R}, "
+                f"nb={nb}, x rows={x.shape[0]}")
+    call = functools.partial(_cheb_dia_kernel, tuple(int(o) for o in offsets),
+                             br=br, bn=bn, interpret=interpret)
     if jnp.iscomplexobj(dvals) or jnp.iscomplexobj(x):
         dr, di = jnp.real(dvals), jnp.imag(dvals)
         xr, xi = jnp.real(x), jnp.imag(x)
-        w1r, w1i = jnp.real(w1), jnp.imag(w1)
-        w2r, w2i = jnp.real(w2), jnp.imag(w2)
-        zeros = jnp.zeros_like(w1r)
         # (Ar + iAi)(xr + ixi): real = Ar xr - Ai xi ; imag = Ar xi + Ai xr
-        yr = call(dr, xr, w1r, w2r, alpha, beta) - (
-            call(di, xi, zeros, zeros, alpha, 0.0)
-        )
-        yi = call(dr, xi, w1i, w2i, alpha, beta) + (
-            call(di, xr, zeros, zeros, alpha, 0.0)
-        )
-        return yr + 1j * yi
-    return call(dvals, x, w1, w2, alpha, beta)
-
-
-def _call_real(offsets, dvals, x, w1, w2, alpha, beta, *, br, bn, interpret):
-    return _cheb_dia_kernel(
-        tuple(int(o) for o in offsets), dvals, x, w1, w2, alpha, beta,
-        br=br, bn=bn, interpret=interpret,
-    )
+        ax = (call(dr, xr) - call(di, xi)) + 1j * (call(dr, xi) + call(di, xr))
+    else:
+        ax = call(dvals, x)
+    return ref.cheb_epilogue(ax, w1, w2, alpha, beta)
 
 
 def _pick_block(n, candidates):

@@ -21,7 +21,11 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --fit-machine --fit-out machine_fit.json
 """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# a CPU compile rehearsal on 512 host devices: pin the CPU (an attached
+# accelerator would otherwise be picked up) and keep the caller's flags
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512")
 
 import argparse
 import functools

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import numpy as np
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -21,7 +21,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) == n:
-        return jax.make_mesh(shape, axes)
+        return jax.make_mesh(shape, axes,
+                             axis_types=(AxisType.Auto,) * len(axes))
     if len(devices) < n:
         raise RuntimeError(
             f"need {n} devices for the production mesh, have {len(devices)}; "

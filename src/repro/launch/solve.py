@@ -39,6 +39,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import os
 
 import numpy as np
 import jax
@@ -46,6 +47,27 @@ import jax
 from ..core import FDConfig, FilterDiag, make_solver_mesh, panel
 from ..core.layouts import Layout
 from ..matrices import get_family
+
+
+#: JAX's persistent compilation cache when ``JAX_COMPILATION_CACHE_DIR``
+#: is not set: a fixed directory in the checkout (the path is part of
+#: the cache key, so it must not move between runs).
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    A set ``JAX_COMPILATION_CACHE_DIR`` is read by JAX itself and is
+    never overridden here; otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def parse_params(s: str) -> dict:
@@ -306,6 +328,7 @@ def main(argv=None):
                          "solo solves (--family etc. are ignored; "
                          "see docs/service.md)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.spmv_schedule != "cyclic" and args.spmv_comm != "compressed" \
             and args.layout != "auto":
         ap.error(f"--spmv-schedule {args.spmv_schedule} requires "

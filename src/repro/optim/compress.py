@@ -18,7 +18,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 BLOCK = 512
@@ -68,7 +68,7 @@ def make_cross_pod_reduce(mesh: Mesh, pspecs, enabled: bool = True):
             return total / n_pods, new_err
 
         fn = shard_map(local, mesh=mesh, in_specs=(spec, spec),
-                       out_specs=(spec, spec), check_rep=False)
+                       out_specs=(spec, spec), check_vma=False)
         return fn(g, err)
 
     def reduce_tree(grads, err_tree):

@@ -210,7 +210,7 @@ class BatchedJob:
             jax.block_until_ready(V)
             redist += 1
             redist_time += time.perf_counter() - t0
-        V = fd._cheb(n_max)(V, jnp.asarray(Mu), alpha, beta)
+        V = fd.filter(V, jnp.asarray(Mu), alpha, beta)
         t0 = time.perf_counter()
         if fd.N_col > 1:
             V = fd.to_stack(V)

@@ -11,7 +11,16 @@ import sys
 
 import pytest
 
-import jax
+# XLA's CPU backend contracts a*b + c into an FMA, or not, depending on
+# the fusion around it, so two engines that issue the same IEEE
+# operations in the same order could still round differently. The
+# engines' bit-identity contract is stated for plain IEEE arithmetic:
+# cap the CPU ISA below FMA here, before JAX starts its CPU backend, and
+# in every subprocess run_distributed starts.
+CPU_FLAGS = "--xla_cpu_max_isa=AVX"
+os.environ["XLA_FLAGS"] = f"{os.environ.get('XLA_FLAGS', '')} {CPU_FLAGS}".strip()
+
+import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", True)  # f64 kernel sweeps + FD residuals
 
@@ -25,7 +34,8 @@ def run_distributed(script: str, n_devices: int = 8, timeout: int = 900,
     Raises on nonzero exit."""
     pre = (
         "import os\n"
-        f"os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count={n_devices}'\n"
+        f"os.environ['XLA_FLAGS'] = "
+        f"'--xla_force_host_platform_device_count={n_devices} {CPU_FLAGS}'\n"
         "import jax\n"
         + ("jax.config.update('jax_enable_x64', True)\n" if x64 else "")
     )

@@ -187,7 +187,7 @@ print("OVERLAP OK")
 def test_overlap_checker_catches_dependent_halo():
     out = run_distributed("""
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.analysis.overlap_check import check_split_phase
 from repro.core import layouts as lo
@@ -204,7 +204,7 @@ def bad_engine(x):
     return y + h
 
 fn = shard_map(bad_engine, mesh=mesh, in_specs=P(None, None),
-               out_specs=P(None, None), check_rep=False)
+               out_specs=P(None, None), check_vma=False)
 x = jax.ShapeDtypeStruct((16, 8), jax.numpy.float64)
 with mesh:
     rep = check_split_phase(fn, x)
@@ -225,7 +225,7 @@ def test_round_pipeline_prefix_chain_proof():
     out = run_distributed("""
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.analysis.overlap_check import check_round_pipeline
 from repro.core import layouts as lo
@@ -270,7 +270,7 @@ def bad_engine(x):
     return y + h1
 
 fn = shard_map(bad_engine, mesh=mesh, in_specs=P(None, None),
-               out_specs=P(None, None), check_rep=False)
+               out_specs=P(None, None), check_vma=False)
 x = jax.ShapeDtypeStruct((16, 8), jax.numpy.float64)
 with mesh:
     bad = check_round_pipeline(fn, x)
@@ -290,7 +290,7 @@ from repro.matrices import SpinChainXXZ
 
 def wrap(iteration, mesh, stack_l):
     from jax import lax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     axes = stack_l.dist_axes
     def mutated(V):
@@ -299,7 +299,7 @@ def wrap(iteration, mesh, stack_l):
         # predicted (kept live so XLA cannot elide it)
         gath = shard_map(lambda x: lax.all_gather(x, axes, tiled=True),
                         mesh=mesh, in_specs=P(axes, None),
-                        out_specs=P(None, None), check_rep=False)(Vs)
+                        out_specs=P(None, None), check_vma=False)(Vs)
         return Vs + 0.0 * gath[: Vs.shape[0]], G
     return mutated
 

@@ -213,39 +213,43 @@ def test_plan_ell_tiles_fallback_seams():
 
 
 def test_ell_spmv_tiled_ragged_nb_falls_back_to_ref(monkeypatch):
-    """On the real-hardware path (interpret=False) a vector count with
-    no kernel block (nb=5) must take the scan fallback — pinned by
-    making the kernel itself raise — and without the fallback operands
-    the seam is a loud ValueError, not silent garbage."""
+    """On the hardware path (interpret=False) a vector count with no
+    kernel lane block (nb=5), or a float64 operand, is refused with
+    KernelRefused before the kernel is called — the scan is never
+    substituted in silence. Interpret mode has bn=1, so the same
+    operands run the kernel there, bit-identical to the scan."""
     rng = np.random.default_rng(1)
     R, Rx, nb = 64, 512, 5
     cols = rng.integers(0, Rx, size=(R, 4)).astype(np.int32)
     vals = rng.standard_normal((R, 4))
     x = rng.standard_normal((Rx, nb))
     tile_cb, tcols, tvals = build_tiles(cols, vals, Rx, br=8, bc=256)
-
-    def boom(*a, **k):
-        raise AssertionError("kernel must not be called on the fallback seam")
-
-    monkeypatch.setattr(ops, "ell_gather_spmv", boom)
     y = np.asarray(ops.ell_spmv_tiled(tile_cb, tcols, tvals,
                                       jnp.asarray(x), br=8, bc=256,
-                                      cols=jnp.asarray(cols),
-                                      vals=jnp.asarray(vals),
-                                      interpret=False))
+                                      interpret=True))
     y_ref = np.asarray(ref.ell_spmv_ref(jnp.asarray(cols), jnp.asarray(vals),
                                         jnp.asarray(x)))
     assert np.array_equal(y, y_ref)
-    with pytest.raises(ValueError, match="no kernel-compatible bn"):
+
+    def boom(*a, **k):
+        raise AssertionError("kernel must not be called on a refused seam")
+
+    monkeypatch.setattr(ops, "ell_gather_spmv", boom)
+    x32 = jnp.asarray(x, jnp.float32)
+    with pytest.raises(ops.KernelRefused, match="nb=5"):
+        ops.ell_spmv_tiled(tile_cb, tcols, tvals.astype(np.float32), x32,
+                           br=8, bc=256, interpret=False)
+    with pytest.raises(ops.KernelRefused, match="float64"):
         ops.ell_spmv_tiled(tile_cb, tcols, tvals, jnp.asarray(x),
                            br=8, bc=256, interpret=False)
 
 
 def test_cheb_dia_fallback_seams_never_touch_kernel(monkeypatch):
     """Every documented ref-fallback seam of ops.cheb_dia — ragged R
-    (no br), ragged nb (no bn on the hardware path), x rows not a
-    multiple of br, force_ref — takes the reference path without
-    invoking the Pallas kernel, pinned by making the kernel raise."""
+    (no br), x rows not a multiple of br, force_ref — takes the
+    reference path without invoking the Pallas kernel, pinned by making
+    the kernel raise. On the hardware path the same shapes, a ragged nb
+    and 64-bit operands raise KernelRefused instead of falling back."""
     def boom(*a, **k):
         raise AssertionError("kernel must not be called on a fallback seam")
 
@@ -266,9 +270,23 @@ def test_cheb_dia_fallback_seams_never_touch_kernel(monkeypatch):
         assert np.array_equal(y, y_ref), (R, nb, Rx, kw)
 
     case(100, 128, 100, interpret=True)   # ragged R: no br divides 100
-    case(128, 100, 128, interpret=False)  # ragged nb on the hardware path
     case(512, 128, 700, interpret=True)   # x rows not a multiple of br=512
     case(128, 128, 128, interpret=True, force_ref=True)
+
+    def hw(R, nb, Rx, dtype):
+        offsets = (-1, 0, 1)
+        ops.cheb_dia(offsets, jnp.asarray(_mk_dia(rng, R, offsets, dtype)),
+                     jnp.zeros((Rx, nb), dtype), jnp.zeros((R, nb), dtype),
+                     jnp.zeros((R, nb), dtype), 0.9, -0.1, interpret=False)
+
+    with pytest.raises(ops.KernelRefused, match="nb=100"):
+        hw(128, 100, 128, np.float32)     # ragged nb on the hardware path
+    with pytest.raises(ops.KernelRefused, match="R=100"):
+        hw(100, 128, 100, np.float32)     # ragged R on the hardware path
+    with pytest.raises(ops.KernelRefused, match="float64"):
+        hw(128, 128, 128, np.float64)
+    with pytest.raises(ops.KernelRefused, match="complex128"):
+        hw(128, 128, 128, np.complex128)
 
 
 def test_cheb_dia_complex_fallback_decides_once(monkeypatch):

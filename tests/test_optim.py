@@ -79,9 +79,10 @@ def test_cross_pod_compression_error_feedback():
     out = __import__("tests.conftest", fromlist=["run_distributed"]).run_distributed(
         """
 import numpy as np, jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P, NamedSharding
+from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
 from repro.optim.compress import make_cross_pod_reduce
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(AxisType.Auto,) * 3)
 spec = {"w": P(None, "model")}
 red = make_cross_pod_reduce(mesh, spec, enabled=True)
 rng = np.random.default_rng(0)
@@ -96,11 +97,11 @@ with mesh:
     def mk(x):
         return jax.device_put(jnp.asarray(x), NamedSharding(mesh, P(None, "model")))
     # emulate pod-varying values with an explicit pod-major concat trick:
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     def podval(_):
         i = jax.lax.axis_index("pod").astype(jnp.float32)
         return jnp.full((16, 8), 1.0 + i)
-    pv = shard_map(podval, mesh=mesh, in_specs=(P(),), out_specs=P(), check_rep=False)(jnp.zeros(()))
+    pv = shard_map(podval, mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False)(jnp.zeros(()))
     g = {"w": pv}
     e = {"w": jnp.zeros((16, 8))}
     (gm, em) = red(g, e)

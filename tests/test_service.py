@@ -102,7 +102,9 @@ def test_pattern_hash_distinct_across_families_and_sizes():
 # -------------------------------------------------- plan serialization --
 
 
-@settings(max_examples=3)
+# deadline=None: one planning pass takes ~0.4 s, over Hypothesis's
+# 200 ms default; the test checks a round trip, not a time
+@settings(max_examples=3, deadline=None)
 @given(spec=st.sampled_from([
     ("SpinChainXXZ", dict(n_sites=8, n_up=4)),
     ("RoadNet", dict(n=500, w=2, m=64, k=4)),
