@@ -9,9 +9,10 @@ complex64 solve of the exciton family checked the same way; the solver at
 deployment size (D = 2,704,156, float64, N_s = 16) for two outer
 iterations, with its SpMV checked against a host CSR matvec; and the
 Pallas DIA kernel against the jnp engine. ``--chips 4`` runs only the
-row-sharded (horizontal-layer) FD iterations on a 2x2 panel and a 4x1
-stack, with both halo-exchange engines, against the same run on one
-chip.
+row-sharded (horizontal) layer on a 2x2 panel and a 4x1 stack, with both
+halo-exchange engines, against one chip: the SpMV, the Lanczos interval
+and a Chebyshev filter at D = 705,432, and converged FD solves at
+D = 924 checked against a dense host ``eigh``.
 
 Every phase raises on failure. The script exits non-zero, and prints no
 result line, when JAX finds no TPU. The last line of stdout is one JSON
@@ -95,16 +96,12 @@ def dense_eigs(mat):
     return np.linalg.eigvalsh(mat.build_csr().to_dense())
 
 
-def csr_matvec(csr, X, chunk=1 << 18):
-    """Host y = A @ X from the CSR arrays, chunked over rows."""
-    Y = np.zeros((csr.shape[0], X.shape[1]), dtype=np.result_type(
-        csr.data, X))
-    for a in range(0, csr.shape[0], chunk):
-        b = min(a + chunk, csr.shape[0])
-        lo, hi = csr.indptr[a], csr.indptr[b]
-        rows = np.repeat(np.arange(b - a), np.diff(csr.indptr[a:b + 1]))
-        np.add.at(Y[a:b], rows, csr.data[lo:hi, None] * X[csr.indices[lo:hi]])
-    return Y
+def host_csr(mat):
+    """The family's own CSR build as a scipy matrix (host float64)."""
+    import scipy.sparse as sp
+
+    c = mat.build_csr()
+    return sp.csr_matrix((c.data, c.indices, c.indptr), shape=c.shape)
 
 
 def eig_phase(label, family, params, fd, bound, why, target):
@@ -160,21 +157,37 @@ def correctness_phases():
             "rounding in the Rayleigh quotient")
 
 
+# Emulated float64 on one v5e, as scripts/f64_reduction_probe.py measured
+# it (chip run; error relative to each operation's own scale): a*b+c
+# elementwise 1.543e-14; a length-D dot or sum 1.4e-13 at most, a norm
+# 5.4e-15; a tall-skinny matmul (the Gram V^T W) 5.605e-07 at D =
+# 2,704,156 and 1.389e-07 at 705,432: XLA:TPU splits a float64 matmul
+# into float32 passes, while a dot of two vectors stays near float64.
+EPS_ELEM = 1.543e-14
+EPS_MATMUL = 5.605e-07
+
+
 def deployment_phase(clock, n_sites=24, n_search=16, target=-10.3,
                      outer=2):
     """SpinChainXXZ at D = C(24, 12) on one chip, float64, two outer
     iterations through the FilterDiag step API (the loop ``solve`` runs),
     timed per iteration with the compile seconds inside each.
 
-    N_s = 16 because emulated float64 is what bounds one chip: compiled
-    for v5e at this D, TSQR needs 7.7 GB of temporaries at N_s = 16 and
-    42 GB at N_s = 32 or 64, past the 15.75 GB of HBM. The target sits
-    just above the ground state (about -10.45), where the spectrum is
-    thin and the filter degree stays low."""
+    N_s = 16 because emulated float64 is what bounds one chip. At this D
+    the chip's compiler refuses TSQR and Rayleigh-Ritz at N_s = 32 and 64
+    ("Ran out of memory in memory space hbm. Used 41.91G of 15.75G hbm"
+    for TSQR at 32): their float64 matmuls and QR keep float32[8, D, N_s]
+    temporaries, padded to 128 lanes. At N_s = 16 TSQR compiles to 8.3 GB
+    of temporaries. The target sits just above the ground state (about
+    -10.45), where the spectrum is thin and the filter degree stays low.
+    The HBM the programs need is printed from ``memory_analysis()``:
+    ``peak_bytes_in_use`` counts the buffers the runtime allocates, not a
+    program's temporaries."""
     import jax
     import jax.numpy as jnp
 
     from repro.core import FDConfig, FilterDiag, make_solver_mesh
+    from repro.core.chebyshev import scale_params
     from repro.matrices import get_family
 
     mat = get_family("SpinChainXXZ", n_sites=n_sites, n_up=n_sites // 2)
@@ -203,7 +216,7 @@ def deployment_phase(clock, n_sites=24, n_search=16, target=-10.3,
         if state.done:
             jax.block_until_ready(state.V)
             break
-        degree = state.pending[1]
+        mu, degree = state.pending
         state = fdd.step_filter(state)
         jax.block_until_ready(state.V)
         print(f"[deploy] outer iteration {it}: degree {degree}, "
@@ -213,6 +226,25 @@ def deployment_phase(clock, n_sites=24, n_search=16, target=-10.3,
               f"{state.history[-1]['best_res']:.3e}", flush=True)
     print(f"[deploy] {state.iteration} outer iterations, "
           f"{state.total_spmvs} SpMVs in all", flush=True)
+    V = state.V
+    programs = {
+        "tsqr": fdd.orthogonalize.lower(V),
+        "ritz": fdd.ritz.func.lower(*fdd.ritz.args, V),
+        f"filter deg {degree}": fdd.filter.func.lower(
+            *fdd.filter.args, V, jnp.asarray(mu), *scale_params(*state.lam)),
+    }
+    need = {}
+    for name, lowered in programs.items():
+        m = lowered.compile().memory_analysis()
+        need[name] = (m.temp_size_in_bytes + m.argument_size_in_bytes
+                      + m.output_size_in_bytes - m.alias_size_in_bytes)
+        print(f"[deploy] {name} program HBM: temp "
+              f"{m.temp_size_in_bytes / 1e9:.3f} GB, arguments "
+              f"{m.argument_size_in_bytes / 1e9:.3f} GB, output "
+              f"{m.output_size_in_bytes / 1e9:.3f} GB", flush=True)
+    name = max(need, key=need.get)
+    print(f"[deploy] largest program HBM {need[name]} bytes "
+          f"({need[name] / 2**30:.3f} GiB, {name})", flush=True)
 
     # the hot path itself: the built stack engine against the host CSR
     rng = np.random.default_rng(0)
@@ -220,16 +252,17 @@ def deployment_phase(clock, n_sites=24, n_search=16, target=-10.3,
     X[: mat.D] = rng.standard_normal((mat.D, 8))
     Xd = jax.device_put(jnp.asarray(X), fdd.stack_layout.vec_sharding(mesh))
     Y = np.asarray(jit_engine()(fdd.spmv_stack, Xd))[: mat.D]
-    Y_ref = csr_matvec(mat.build_csr(), X[: mat.D])
+    Y_ref = host_csr(mat) @ X[: mat.D]
     rel = float(np.abs(Y - Y_ref).max() / np.abs(Y_ref).max())
     bound = 1e-10
     print(f"[deploy] SpMV vs host CSR matvec: max rel error {rel:.3e} "
           f"(bound {bound:.0e}: rows hold <= {ell.cols.shape[-1]} terms, "
-          "so float64 rounding stays near 1e-15 while a wrong entry or a "
-          "float32 downcast shows at >= 1e-8)", flush=True)
+          f"so emulated float64 rounding stays near {ell.cols.shape[-1]} * "
+          f"{EPS_ELEM:.2e} while a wrong entry or a float32 downcast shows "
+          "at >= 1e-8)", flush=True)
     peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use", -1)
-    print(f"[deploy] peak_bytes_in_use {peak} ({peak / 2**30:.3f} GiB)",
-          flush=True)
+    print(f"[deploy] peak_bytes_in_use {peak} ({peak / 2**30:.3f} GiB; "
+          "runtime buffers only)", flush=True)
     if not rel <= bound:
         raise RuntimeError(f"deployment SpMV error {rel:.3e} > {bound:.0e}")
 
@@ -292,86 +325,176 @@ def kernel_phase(n=65536, nb=256):
                            "refused on TPU")
 
 
-def four_chip_phase(n_sites=22, n_target=4, n_search=16, target=-9.4,
-                    outer=1):
-    """The horizontal layer across chips: the same FD run on a 2x2 panel
-    and a 4x1 stack with each halo-exchange engine, against a 1x1 mesh.
-    Each run applies its built SpMV engine to one seeded block and then
-    takes ``outer`` outer iterations from the same seed."""
+def four_chip_phase(n_sites=22, n_search=16, degree=32, small_sites=12):
+    """The horizontal layer across chips, against one chip in the same
+    process.
+
+    At D = C(22, 11) = 705,432, float64, each mesh (a 2x2 panel and a 4x1
+    stack, with the a2a and the compressed halo exchange) applies its
+    SpMV engine to one seeded block, runs FD's Lanczos interval, and
+    applies one degree-32 Chebyshev filter to the block; each output is
+    compared with the 1x1 mesh's and the SpMV also with the host CSR.
+    Then FD solves SpinChainXXZ n_sites=12 to convergence on the 2x2
+    panel and the 4x1 stack; its eigenvalues are compared with a dense
+    host ``eigh`` and with the 1x1 solve, and the residual of every
+    returned pair is recomputed on the host. FD does not converge the
+    D = 705,432 chain within a chip run (its outer loop needs tens of
+    iterations there), so converged eigenvalues are compared at D = 924.
+    """
     import jax
     import jax.numpy as jnp
 
     from repro.core import FDConfig, FilterDiag, make_solver_mesh
+    from repro.core.filters import build_filter
+    from repro.core.chebyshev import scale_params
     from repro.matrices import get_family
 
-    mat = get_family("SpinChainXXZ", n_sites=n_sites, n_up=n_sites // 2)
     devs = jax.devices()
-    X = np.random.default_rng(2).standard_normal((mat.D, n_search))
     apply_engine = jit_engine()
+    meshes = [(1, 1, "panel", "a2a"), (2, 2, "panel", "a2a"),
+              (2, 2, "panel", "compressed"), (4, 1, "stack", "a2a"),
+              (4, 1, "stack", "compressed")]
 
-    def run(n_row, n_col, layout, comm):
+    def build(mat, n_row, n_col, layout, comm, **fd_kw):
         mesh = make_solver_mesh(n_row, n_col, devices=devs[: n_row * n_col])
         # SVQB: a float64 QR inside shard_map does not compile across
         # TPU chips, so TSQR is refused there (FilterDiag says so)
-        fd = FDConfig(n_target=n_target, n_search=n_search, target=target,
-                      max_iters=outer, layout=layout, spmv_comm=comm,
-                      ortho="svqb")
+        fd = FDConfig(n_search=n_search, layout=layout, spmv_comm=comm,
+                      ortho="svqb", **fd_kw)
+        return mesh, FilterDiag(mat, mesh, fd)
+
+    def placed(V):
+        return sorted((s.device.id, s.index[0].start or 0)
+                      for s in V.addressable_shards)
+
+    # -- the layer at D = 705,432 ----------------------------------------
+    mat = get_family("SpinChainXXZ", n_sites=n_sites, n_up=n_sites // 2)
+    A = host_csr(mat)
+    terms = int(np.diff(A.indptr).max())
+    X = np.random.default_rng(2).standard_normal((mat.D, n_search))
+    Y_host = A @ X
+    print(f"[4chip] SpinChainXXZ n_sites={n_sites} D={mat.D} float64 "
+          f"n_search={n_search}, <= {terms} terms per row", flush=True)
+    # SpMV: each row's terms in float64, summed in an order the halo
+    # split may change: |error| <= terms * EPS_ELEM of the row's scale
+    b_spmv = 4 * terms * EPS_ELEM
+    # the Lanczos coefficients are a dot and a norm of length D, summed
+    # across the chips: measure that reduction's error on this mesh
+    mesh4 = make_solver_mesh(4, 1, devices=devs[:4])
+    P4 = jax.sharding.PartitionSpec(mesh4.axis_names[0])
+    sh4 = jax.sharding.NamedSharding(mesh4, P4)
+    n4 = mat.D - mat.D % 4
+    x4, z4 = X[:n4, 0], X[:n4, 1]
+    xl, zl = x4.astype(np.longdouble), z4.astype(np.longdouble)
+    xd, zd = jax.device_put(x4, sh4), jax.device_put(z4, sh4)
+    e_dot = float(abs(np.longdouble(float(jax.jit(jnp.vdot)(xd, zd)))
+                      - np.sum(xl * zl)) / np.sum(np.abs(xl * zl)))
+    nrm = np.sqrt(np.sum(xl * xl))
+    e_nrm = float(abs(np.longdouble(float(jax.jit(jnp.linalg.norm)(xd)))
+                      - nrm)
+                  / nrm)
+    e_red = max(e_dot, e_nrm, EPS_ELEM)
+    print(f"[4chip] float64 reductions over 4 chips, length {n4}: dot "
+          f"rel error {e_dot:.3e}, norm {e_nrm:.3e}", flush=True)
+    # Lanczos interval: 30 steps, each coefficient off by <= e_red *
+    # ||A||; Weyl on the tridiagonal moves its extremal Ritz values, and
+    # their residual bounds, by at most steps * e_red * ||A||
+    b_lam = 4 * 30 * e_red
+    # filter: degree SpMVs and axpys, no reductions; a Chebyshev
+    # recurrence on [-1, 1] grows a perturbation at most degree^2-fold
+    b_filt = 4 * degree ** 2 * terms * EPS_ELEM
+    ref = None
+    failed = []
+    for n_row, n_col, layout, comm in meshes:
         t0 = time.perf_counter()
-        fdd = FilterDiag(mat, mesh, fd)
+        mesh, fdd = build(mat, n_row, n_col, layout, comm, n_target=4)
         Xp = np.zeros((fdd.D_pad, n_search))
         Xp[: mat.D] = X
         Xd = jax.device_put(jnp.asarray(Xp),
                             fdd.stack_layout.vec_sharding(mesh))
         y = np.asarray(apply_engine(fdd.spmv_stack, Xd))[: mat.D]
+        lam = np.array(fdd.init_state().lam)
+        if ref is None:
+            poly = build_filter((lam[0], lam[0] + 0.1 * (lam[1] - lam[0])),
+                                tuple(lam), degree=degree)
+            coeffs = (jnp.asarray(poly.mu), *scale_params(*lam))
+        V = fdd.to_panel(Xd) if fdd.N_col > 1 else Xd
+        V = fdd.filter(V, *coeffs)
+        Vs = fdd.to_stack(V) if fdd.N_col > 1 else V
+        f = np.asarray(Vs)[: mat.D]
+        tag = f"{layout} {n_row}x{n_col} {comm}"
+        print(f"[4chip] {tag}: mesh {dict(mesh.shape)} devices "
+              f"{[d.id for d in mesh.devices.flat]}, lanczos "
+              f"({lam[0]:+.12f}, {lam[1]:+.12f}), "
+              f"{time.perf_counter() - t0:.3f} s with compiles", flush=True)
+        print(f"[4chip]   shards (device id, first row): stack "
+              f"{placed(Xd)} filter layout {placed(V)}", flush=True)
+        e_h = float(np.abs(y - Y_host).max() / np.abs(Y_host).max())
+        if ref is None:
+            ref = (y, lam, f)
+            print(f"[4chip]   SpMV vs host CSR: max rel error {e_h:.3e} "
+                  f"(bound {b_spmv:.1e})", flush=True)
+            if not e_h <= b_spmv:
+                failed.append(f"{tag} spmv vs host")
+            continue
+        e_y = float(np.abs(y - ref[0]).max() / np.abs(ref[0]).max())
+        e_l = float(np.abs(lam - ref[1]).max() / np.abs(ref[1]).max())
+        e_f = float(np.abs(f - ref[2]).max() / np.abs(ref[2]).max())
+        print(f"[4chip]   vs 1x1: SpMV max rel diff {e_y:.3e} (bound "
+              f"{b_spmv:.1e}; vs host {e_h:.3e}), lanczos interval rel "
+              f"diff {e_l:.3e} (bound {b_lam:.1e}), degree-{degree} filter "
+              f"max rel diff {e_f:.3e} (bound {b_filt:.1e})", flush=True)
+        if not (e_y <= b_spmv and e_h <= b_spmv and e_l <= b_lam
+                and e_f <= b_filt):
+            failed.append(tag)
+
+    # -- converged eigenpairs at D = 924 ---------------------------------
+    small = get_family("SpinChainXXZ", n_sites=small_sites,
+                       n_up=small_sites // 2)
+    A = host_csr(small)
+    target, tol, n_target = 0.05, 1e-8, 4
+    lam_ref = nearest_eigs(dense_eigs(small), target, n_target)
+    print(f"[4chip] SpinChainXXZ n_sites={small_sites} D={small.D} float64 "
+          f"target={target} tol={tol}: reference "
+          f"{np.array2string(lam_ref, precision=12)}", flush=True)
+    # a Hermitian Ritz value lies within its residual norm of an
+    # eigenvalue; the host recomputes that norm in IEEE float64, off from
+    # the device's by its Rayleigh-Ritz matmuls: <= N_s * EPS_MATMUL * ||A||
+    norm_a = float(abs(A).sum(axis=1).max())
+    b_res = tol + 4 * n_search * EPS_MATMUL * norm_a
+    th1 = None
+    for n_row, n_col, layout, comm in (meshes[0], meshes[1], meshes[4]):
+        t0 = time.perf_counter()
+        mesh, fdd = build(small, n_row, n_col, layout, comm,
+                          n_target=n_target, target=target, tol=tol,
+                          max_iters=25)
         state = fdd.init_state()
         while not state.done:
             state = fdd.step(state)
         res = state.result
-        placed = {}
-        for tag, V in (("stack", state.V), ("panel", fdd.to_panel(state.V))):
-            placed[tag] = sorted((s.device.id, s.index[0].start or 0)
-                                 for s in V.addressable_shards)
-        print(f"[4chip] {layout} {n_row}x{n_col} comm={comm} mesh "
-              f"{dict(mesh.shape)} devices {[d.id for d in mesh.devices.flat]}"
-              f": lanczos ({state.lam[0]:+.12f}, {state.lam[1]:+.12f}), "
-              f"{res.iterations} outer iterations / {res.total_spmvs} "
-              f"SpMVs, {time.perf_counter() - t0:.3f} s with compiles",
-              flush=True)
-        print(f"[4chip]   shards (device id, first row): stack "
-              f"{placed['stack']} panel {placed['panel']}", flush=True)
-        return y, np.array(state.lam), nearest_eigs(res.eigenvalues,
-                                                     target, n_target)
-
-    print(f"[4chip] SpinChainXXZ n_sites={n_sites} D={mat.D} float64 "
-          f"n_search={n_search} target={target}", flush=True)
-    y1, lam1, th1 = run(1, 1, "panel", "a2a")
-    print(f"[4chip] 1x1 Ritz values {np.array2string(th1, precision=12)}",
-          flush=True)
-    # SpMV: the engines add each row's <= n_sites terms in one slot
-    # order on every mesh; only the halo exchange differs, and it moves
-    # values without arithmetic
-    b_spmv = 1e-12
-    # Lanczos bounds: extremal Ritz values of a 30-step Krylov space,
-    # stable to float64 rounding of its dots
-    b_lam = 1e-9
-    # Ritz values after one filter pass: on the chip this iteration's 1x1
-    # Ritz values differ from a CPU float64 run of it by 6.5e-5 (TPU
-    # float64 dense algebra is not IEEE float64); meshes are held to 10x
-    b_ritz = 1e-3
-    failed = []
-    for n_row, n_col, layout in ((2, 2, "panel"), (4, 1, "stack")):
-        for comm in ("a2a", "compressed"):
-            y, lam, th = run(n_row, n_col, layout, comm)
-            e_y = float(np.abs(y - y1).max() / np.abs(y1).max())
-            e_l = float(np.abs(lam - lam1).max() / np.abs(lam1).max())
-            e_t = float(np.abs(th - th1).max())
-            print(f"[4chip] {layout} {n_row}x{n_col} {comm} vs 1x1: SpMV "
-                  f"max rel diff {e_y:.3e} (bound {b_spmv:.0e}), lanczos "
-                  f"bounds rel diff {e_l:.3e} (bound {b_lam:.0e}), Ritz "
-                  f"values max |diff| {e_t:.3e} (bound {b_ritz:.0e})",
-                  flush=True)
-            if not (e_y <= b_spmv and e_l <= b_lam and e_t <= b_ritz):
-                failed.append(f"{layout} {n_row}x{n_col} {comm}")
+        theta, _, _, VY = fdd.ritz(fdd.orthogonalize(state.V))
+        theta = np.asarray(theta)
+        pick = [int(np.argmin(np.abs(theta - e))) for e in res.eigenvalues]
+        Vh = fdd.gather_global(VY)[:, pick]
+        r_host = np.linalg.norm(A @ Vh - Vh * theta[pick], axis=0) \
+            / np.linalg.norm(Vh, axis=0)
+        th = nearest_eigs(res.eigenvalues, target, n_target)
+        err = float(np.abs(th - lam_ref).max()) if len(th) == n_target \
+            else float("inf")
+        tag = f"{layout} {n_row}x{n_col} {comm}"
+        line = (f"[4chip] {tag} n_sites={small_sites}: {res.n_converged} "
+                f"converged in {res.iterations} iterations, "
+                f"{time.perf_counter() - t0:.3f} s with compiles; max "
+                f"|lambda - lambda_ref| {err:.3e} (bound {tol:.0e}); host "
+                f"residuals max {r_host.max():.3e} (bound {b_res:.2e})")
+        if th1 is None:
+            th1 = th
+        elif len(th) == n_target:
+            line += f"; vs 1x1 max |diff| {np.abs(th - th1).max():.3e}"
+        print(line, flush=True)
+        if not (res.n_converged >= n_target and err <= tol
+                and r_host.max() <= b_res):
+            failed.append(f"{tag} n_sites={small_sites}")
     if failed:
         raise RuntimeError(f"[4chip] out of bounds: {failed}")
 

@@ -102,7 +102,6 @@ from jax import lax
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..kernels.ref import cheb_epilogue
 from ..matrices.families import MatrixFamily
 from ..matrices.sparse import CSR
 from .layouts import Layout
@@ -1090,7 +1089,7 @@ def _build_engine(mesh: Mesh, layout: Layout, ell: DistEll, *, use_kernel: bool,
             dev = [a[0] for a in ins[:n_args]]
             w1, w2, a, b = ins[n_args:]
             y = body(w1, *dev)
-            return cheb_epilogue(y, w1, w2, a, b)
+            return 2.0 * a * y + 2.0 * b * w1 - w2
 
         fn = shard_map(
             local_fn_fused,

@@ -27,17 +27,13 @@ traffic factor at κ=5; on TPU we additionally re-think the *format*:
     grid step's diagonal is picked with a one-hot lane select and a lane
     sum, which is exact (every other lane adds 0).
 
-  * The kernel computes A x only. The three-term epilogue 2a*(A x) +
-    2b*w1 - w2 is :func:`ref.cheb_epilogue`, applied by the caller in the
-    same expression the jnp engines use: inside the kernel it was
-    contracted into an FMA in interpret mode while the engines' epilogue
-    is not, and the kernel-on and kernel-off engines must agree bit for
-    bit. The price on TPU is one more pass over a vector block (the
-    kernel writes A x, the epilogue reads it back).
+  * The fused epilogue 2a*(A x) + 2b*w1 - w2 runs on the last diagonal,
+    so W2 is read exactly once from HBM (κ = 5, not 6 — paper §3.2).
 
 Block sizes: BR rows (multiple of 8 sublanes) x BN vector columns
-(multiple of 128 lanes). VMEM footprint/step ≈ (2 x-tiles + out + rotate
-temp) * BR * BN * 4B + BR * 128 * 4B ≈ 2.3 MiB « 16 MiB at 512 x 256.
+(multiple of 128 lanes). VMEM footprint/step ≈ (2 x-tiles + w1 + w2 + out
++ rotate temp) * BR * BN * 4B + BR * 128 * 4B ≈ 3.3 MiB « 16 MiB at
+512 x 256.
 
 Complex matrices are handled in ops.py by splitting into real/imag DIA
 planes (TPU has no native complex VREG type); this kernel is real-only.
@@ -51,13 +47,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ref import cheb_epilogue
-
 DEFAULT_BR = 512
 DEFAULT_BN = 256
 
 
-def _kernel(off_blk, rot, dvals, x0, x1, out):
+def _kernel(off_blk, rot, ab, dvals, x0, x1, w1, w2, out):
     d = pl.program_id(2)
     br = out.shape[0]
 
@@ -73,17 +67,27 @@ def _kernel(off_blk, rot, dvals, x0, x1, out):
     dv = jnp.sum(jnp.where(lane == d, dvb, 0), axis=1, keepdims=True)
     out[...] += dv * xs
 
+    @pl.when(d == pl.num_programs(2) - 1)
+    def _epilogue():
+        a2 = 2.0 * ab[0]
+        b2 = 2.0 * ab[1]
+        out[...] = a2 * out[...] + b2 * w1[...] - w2[...]
+
 
 @functools.partial(jax.jit, static_argnames=("offsets", "br", "bn", "interpret"))
-def dia_spmv(
+def cheb_dia(
     offsets: tuple[int, ...],
     dvals: jax.Array,  # [n_diag, R] per-diagonal values (0 where invalid)
     x: jax.Array,      # [Rx, nb], Rx >= R (halo may be appended)
+    w1: jax.Array,     # [R, nb]
+    w2: jax.Array,     # [R, nb]
+    alpha,
+    beta,
     br: int = DEFAULT_BR,
     bn: int = DEFAULT_BN,
     interpret: bool = False,
 ):
-    """A @ x [R, nb] for the DIA matrix given by (offsets, dvals).
+    """y = 2a*(A@x) + 2b*w1 - w2 for the DIA matrix given by (offsets, dvals).
 
     Rows where i + offset falls outside [0, Rx) must carry dvals == 0 (the
     host builder guarantees this); their x tiles are clamped loads whose
@@ -98,11 +102,13 @@ def dia_spmv(
     # left rotation by the in-block shift o % br, as jnp.roll's amount
     rot = jnp.asarray([(2 * br - o % br) % (2 * br) for o in offsets],
                       jnp.int32)
+    ab = jnp.stack([jnp.asarray(alpha, dvals.dtype),
+                    jnp.asarray(beta, dvals.dtype)])
 
     grid = (R // br, nb // bn, n_diag)
 
     def x_map(k):  # k = 0 or 1: aligned block at floor(offset/br) + k, clamped
-        def im(rb, cb, d, off_blk_ref, rot_ref):
+        def im(rb, cb, d, off_blk_ref, rot_ref, ab_ref):
             blk = rb + off_blk_ref[d] + k
             # explicit int32 bounds: under x64 jnp.clip's weak int64
             # bounds send Mosaic's convert lowering into a recursion
@@ -111,8 +117,11 @@ def dia_spmv(
 
         return im
 
+    def vec_map(rb, cb, d, *_):
+        return rb, cb
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=grid,
         in_specs=[
             # dvals.T; int32 zero: under x64 a Python 0 lowers as i64
@@ -120,19 +129,14 @@ def dia_spmv(
                          lambda rb, cb, d, *_: (rb, jnp.int32(0))),
             pl.BlockSpec((br, bn), x_map(0)),
             pl.BlockSpec((br, bn), x_map(1)),
+            pl.BlockSpec((br, bn), vec_map),  # w1
+            pl.BlockSpec((br, bn), vec_map),  # w2
         ],
-        out_specs=pl.BlockSpec((br, bn), lambda rb, cb, d, *_: (rb, cb)),
+        out_specs=pl.BlockSpec((br, bn), vec_map),
     )
     return pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, nb), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((R, nb), w1.dtype),
         interpret=interpret,
-    )(off_blk, rot, dvals.T, x, x)
-
-
-def cheb_dia(offsets, dvals, x, w1, w2, alpha, beta, br: int = DEFAULT_BR,
-             bn: int = DEFAULT_BN, interpret: bool = False):
-    """y = 2a*(A@x) + 2b*w1 - w2 for the DIA matrix given by (offsets, dvals)."""
-    ax = dia_spmv(offsets, dvals, x, br=br, bn=bn, interpret=interpret)
-    return cheb_epilogue(ax, w1, w2, alpha, beta)
+    )(off_blk, rot, ab, dvals.T, x, x, w1, w2)

@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from .cheb_dia import dia_spmv as _cheb_dia_kernel
+from .cheb_dia import cheb_dia as _cheb_dia_kernel
 from .ell_gather import build_tiles, ell_gather_spmv
 
 #: Lane-block candidates for the vector count: the hardware path needs a
@@ -311,11 +311,18 @@ def cheb_dia(offsets, dvals, x, w1, w2, alpha, beta, *, interpret=None,
     if jnp.iscomplexobj(dvals) or jnp.iscomplexobj(x):
         dr, di = jnp.real(dvals), jnp.imag(dvals)
         xr, xi = jnp.real(x), jnp.imag(x)
+        w1r, w1i = jnp.real(w1), jnp.imag(w1)
+        w2r, w2i = jnp.real(w2), jnp.imag(w2)
+        zeros = jnp.zeros_like(w1r)
         # (Ar + iAi)(xr + ixi): real = Ar xr - Ai xi ; imag = Ar xi + Ai xr
-        ax = (call(dr, xr) - call(di, xi)) + 1j * (call(dr, xi) + call(di, xr))
-    else:
-        ax = call(dvals, x)
-    return ref.cheb_epilogue(ax, w1, w2, alpha, beta)
+        yr = call(dr, xr, w1r, w2r, alpha, beta) - (
+            call(di, xi, zeros, zeros, alpha, 0.0)
+        )
+        yi = call(dr, xi, w1i, w2i, alpha, beta) + (
+            call(di, xr, zeros, zeros, alpha, 0.0)
+        )
+        return yr + 1j * yi
+    return call(dvals, x, w1, w2, alpha, beta)
 
 
 def _pick_block(n, candidates):

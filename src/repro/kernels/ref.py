@@ -39,17 +39,6 @@ def ell_spmv_split_ref(cols_loc, vals_loc, cols_halo, vals_halo, x, halo):
     return y
 
 
-def cheb_epilogue(y, w1, w2, alpha, beta):
-    """The three-term recurrence step 2a*y + 2b*w1 - w2 on y = A @ w1.
-
-    Every fused engine, kernelized or not, applies this one expression
-    in its shard_map body: XLA's CPU backend contracts ``a*y + c`` into
-    an FMA or not depending on the surrounding program, so engines that
-    spelled it differently (or applied it inside a Pallas kernel) would
-    round differently and lose bit-identity."""
-    return 2.0 * alpha * y + 2.0 * beta * w1 - w2
-
-
 def cheb_dia_ref(offsets, dvals, x, w1, w2, alpha, beta):
     """Fused Chebyshev step for a DIA (diagonal-offset) matrix.
 
@@ -68,4 +57,4 @@ def cheb_dia_ref(offsets, dvals, x, w1, w2, alpha, beta):
         ok = (j >= 0) & (j < x.shape[0])
         xo = jnp.take(x, jnp.clip(j, 0, x.shape[0] - 1), axis=0)
         acc = acc + jnp.where(ok[:, None], dvals[d][:, None] * xo, 0)
-    return cheb_epilogue(acc, w1, w2, alpha, beta)
+    return 2.0 * alpha * acc + 2.0 * beta * w1 - w2

@@ -11,16 +11,14 @@ import sys
 
 import pytest
 
-# XLA's CPU backend contracts a*b + c into an FMA, or not, depending on
-# the fusion around it, so two engines that issue the same IEEE
-# operations in the same order could still round differently. The
-# engines' bit-identity contract is stated for plain IEEE arithmetic:
-# cap the CPU ISA below FMA here, before JAX starts its CPU backend, and
-# in every subprocess run_distributed starts.
-CPU_FLAGS = "--xla_cpu_max_isa=AVX"
-os.environ["XLA_FLAGS"] = f"{os.environ.get('XLA_FLAGS', '')} {CPU_FLAGS}".strip()
+import jax
 
-import jax  # noqa: E402
+# XLA's CPU backend contracts a*b + c into an FMA, or not, depending on
+# the fusion around it, so two differently structured programs that
+# issue the same IEEE operations in the same order can still round
+# differently. A test that demands bit-identity between such programs
+# (kernel on vs off, s-step vs s = 1) runs its child with FMA off.
+NO_FMA = "--xla_cpu_max_isa=AVX"
 
 jax.config.update("jax_enable_x64", True)  # f64 kernel sweeps + FD residuals
 
@@ -29,13 +27,14 @@ SRC = os.path.join(ROOT, "src")
 
 
 def run_distributed(script: str, n_devices: int = 8, timeout: int = 900,
-                    x64: bool = True) -> str:
+                    x64: bool = True, xla_flags: str = "") -> str:
     """Run ``script`` in a subprocess with n fake CPU devices; returns stdout.
+    ``xla_flags`` are appended to the child's XLA_FLAGS (e.g. ``NO_FMA``).
     Raises on nonzero exit."""
     pre = (
         "import os\n"
         f"os.environ['XLA_FLAGS'] = "
-        f"'--xla_force_host_platform_device_count={n_devices} {CPU_FLAGS}'\n"
+        f"'--xla_force_host_platform_device_count={n_devices} {xla_flags}'\n"
         "import jax\n"
         + ("jax.config.update('jax_enable_x64', True)\n" if x64 else "")
     )
