@@ -61,16 +61,25 @@ def main(argv=None):
 
     d0 = devs[0]
     xd, yd, Xd = (jax.device_put(a, d0) for a in (x, y, X))
+
+    def highest(f):  # traced as the solver traces its dense algebra
+        def g(*a):
+            with jax.default_matmul_precision("highest"):
+                return f(*a)
+        return jax.jit(g)
+
     one = {
         "vdot": jax.jit(jnp.vdot)(xd, yd),
+        "vdot highest": highest(jnp.vdot)(xd, yd),
         "sum(x*y)": jax.jit(lambda a, b: jnp.sum(a * b))(xd, yd),
+        "sum(x*y) highest": highest(lambda a, b: jnp.sum(a * b))(xd, yd),
         "norm": jax.jit(jnp.linalg.norm)(xd),
         "gram highest": jax.jit(
             lambda A: jnp.matmul(A.T, A, precision=hi))(Xd),
         "gram default": jax.jit(lambda A: A.T @ A)(Xd),
     }
-    report("1 device", "vdot", _rel(one["vdot"], dot_ref, dot_scale))
-    report("1 device", "sum(x*y)", _rel(one["sum(x*y)"], dot_ref, dot_scale))
+    for k in ("vdot", "vdot highest", "sum(x*y)", "sum(x*y) highest"):
+        report("1 device", k, _rel(one[k], dot_ref, dot_scale))
     report("1 device", "norm", _rel(one["norm"], nrm_ref, nrm_ref))
     for k in ("gram highest", "gram default"):
         report("1 device", k, _rel(one[k], gram_ref, gram_scale))
