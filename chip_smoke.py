@@ -48,35 +48,6 @@ def device_phase(n_chips: int):
     return devs
 
 
-class CompileClock:
-    """Seconds JAX spends tracing, lowering, compiling or reading compiled
-    programs from the persistent cache, read from ``jax.monitoring``."""
-
-    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration",
-              "/jax/compilation_cache/cache_retrieval_time_sec")
-
-    def __init__(self):
-        import jax
-
-        self.secs = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **_):
-        if event in self.EVENTS:
-            self.secs += secs
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-
 def jit_engine():
     """jit(engine, *args): the engine is an argument, so its operator
     arrays are parameters of the program, not constants baked into it."""
@@ -507,13 +478,13 @@ def main(argv=None):
 
     import jax
 
+    from repro.core.spans import COMPILES as clock
     from repro.launch.solve import enable_compile_cache
 
     jax.config.update("jax_enable_x64", True)
     print(f"[cache] compilation cache at {enable_compile_cache()}",
           flush=True)
-    clock = CompileClock()
-    t0 = time.perf_counter()
+    c0, t0 = clock.secs, time.perf_counter()
     if args.chips == 4:
         four_chip_phase()
     else:
@@ -521,7 +492,7 @@ def main(argv=None):
         deployment_phase(clock)
         kernel_phase()
     print(f"[cache] {time.perf_counter() - t0:.3f} s in all, compile "
-          f"{clock.secs:.3f} s; persistent cache hits {clock.hits}, "
+          f"{clock.secs - c0:.3f} s; persistent cache hits {clock.hits}, "
           f"misses {clock.misses}", flush=True)
     d = devs[0]
     print(json.dumps({"ok": True, "device": {

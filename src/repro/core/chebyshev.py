@@ -11,6 +11,7 @@ Also provides KPM moment accumulation (used for the DOS panels, Figs 7/8).
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -48,16 +49,22 @@ def chebyshev_filter(spmv, mu, alpha: float, beta: float, V, fused_step=None):
 
     if fused_step is None:
         def fused_step(w1, w2, alpha_, beta_):
-            return 2 * a * spmv(w1) + 2 * b * w1 - w2  # fused SpMV+axpy
+            y = spmv(w1)
+            with jax.named_scope("fd.cheb_update"):
+                return 2 * a * y + 2 * b * w1 - w2  # fused SpMV+axpy
 
-    W1 = a * spmv(V) + b * V                     # T1
+    y = spmv(V)
+    with jax.named_scope("fd.cheb_update"):
+        W1 = a * y + b * V                       # T1
     W2 = fused_step(W1, V, alpha, beta)          # T2
-    Y = mu[0] * V + mu[1] * W1 + mu[2] * W2
+    with jax.named_scope("fd.cheb_update"):
+        Y = mu[0] * V + mu[1] * W1 + mu[2] * W2
 
     def body(carry, mu_k):
         Y, Tkm1, Tkm2 = carry
         Tk = fused_step(Tkm1, Tkm2, alpha, beta)
-        Y = Y + mu_k * Tk
+        with jax.named_scope("fd.cheb_update"):
+            Y = Y + mu_k * Tk
         return (Y, Tk, Tkm1), None
 
     if n >= 3:
@@ -93,7 +100,8 @@ def chebyshev_filter_sstep(group, mu, alpha: float, beta: float, V, s: int):
 
     def acc(Yk, mu_T):
         mu_k, Tk = mu_T
-        return Yk + mu_k * Tk, None
+        with jax.named_scope("fd.cheb_update"):
+            return Yk + mu_k * Tk, None
 
     Ts, w1, w2 = group(s1, True)(V, alpha, beta)
     Y = mu[0] * V + mu[1] * Ts[0] + mu[2] * Ts[1]

@@ -39,7 +39,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 
 import numpy as np
 import jax
@@ -53,6 +52,7 @@ from .lanczos import lanczos_interval
 from .layouts import Layout
 from .orthogonalize import make_gram, make_svqb, make_tsqr
 from .redistribute import make_redistribute
+from .spans import span
 from .spmv import (build_dist_ell, build_sstep_ell, make_fused_cheb_step,
                    make_spmv, make_sstep_cheb)
 
@@ -93,8 +93,6 @@ class FDResult:
     iterations: int
     total_spmvs: int
     redistributions: int
-    wall_time: float
-    redist_time: float
     history: list
 
 
@@ -120,20 +118,20 @@ class FDState:
     iteration: int = 0
     total_spmvs: int = 0
     redistributions: int = 0
-    redist_time: float = 0.0
-    wall_time: float = 0.0
     history: list = dataclasses.field(default_factory=list)
     pending: tuple | None = None   # (mu [deg+1], degree) awaiting step_filter
     done: bool = False
     result: FDResult | None = None
 
 
-def _jit_full_precision(f):
-    """jit ``f`` traced with full-precision matmuls. A TPU runs float32
-    dots in bfloat16 passes by default, which leaves Rayleigh-Ritz
-    residuals near 1e-3; CPU dots are full precision either way."""
+def _jit_full_precision(f, name: str):
+    """jit ``f`` traced with full-precision matmuls, its ops in the named
+    scope ``fd.<name>``. A TPU runs float32 dots in bfloat16 passes by
+    default, which leaves Rayleigh-Ritz residuals near 1e-3; CPU dots are
+    full precision either way."""
     def traced(*args):
-        with jax.default_matmul_precision("highest"):
+        with jax.default_matmul_precision("highest"), \
+                jax.named_scope("fd." + name):
             return f(*args)
 
     return jax.jit(traced)
@@ -318,10 +316,10 @@ class FilterDiag:
         if cfg.ortho == "tsqr":
             self._tsqr = make_tsqr(mesh, self.stack_layout)
             self.orthogonalize = _jit_full_precision(
-                lambda V: self._tsqr(V)[0])
+                lambda V: self._tsqr(V)[0], "orthogonalize")
         else:
             self.orthogonalize = _jit_full_precision(
-                make_svqb(mesh, self.stack_layout))
+                make_svqb(mesh, self.stack_layout), "orthogonalize")
         self.gram = make_gram(mesh, self.stack_layout)
         self.to_panel, self.to_stack = make_redistribute(
             mesh, self.stack_layout, self.panel_layout, impl=cfg.redist_impl
@@ -331,19 +329,22 @@ class FilterDiag:
 
         def ritz(spmv, V):
             AV = spmv(V)
-            H = self.gram(V, AV)  # [Ns, Ns] replicated
-            H = 0.5 * (H + jnp.conj(H.T))
-            theta, Y = jnp.linalg.eigh(H)
+            with jax.named_scope("fd.gram"):
+                H = self.gram(V, AV)  # [Ns, Ns] replicated
+                H = 0.5 * (H + jnp.conj(H.T))
+            with jax.named_scope("fd.eigh"):
+                theta, Y = jnp.linalg.eigh(H)
             # residual norms: || AV y - θ V y ||
-            AVY = AV @ Y.astype(AV.dtype)
-            VY = V @ Y.astype(V.dtype)
-            Rm = AVY - VY * theta[None, :].astype(VY.dtype)
-            res = jnp.sqrt(jnp.sum(jnp.abs(Rm) ** 2, axis=0))
+            with jax.named_scope("fd.residuals"):
+                AVY = AV @ Y.astype(AV.dtype)
+                VY = V @ Y.astype(V.dtype)
+                Rm = AVY - VY * theta[None, :].astype(VY.dtype)
+                res = jnp.sqrt(jnp.sum(jnp.abs(Rm) ** 2, axis=0))
             return theta, Y, res, VY
 
         # the engines are passed in, not captured, so their operator
         # arrays are program parameters rather than baked-in constants
-        self.ritz = functools.partial(_jit_full_precision(ritz),
+        self.ritz = functools.partial(_jit_full_precision(ritz, "ritz"),
                                       self.spmv_stack)
         # filter(V, mu, alpha, beta): one compiled program per degree, the
         # static length of mu
@@ -351,8 +352,9 @@ class FilterDiag:
             self.filter = jax.jit(self.cheb_sstep)
         else:
             def run(spmv, fused_step, V, mu, alpha, beta):
-                return chebyshev_filter(spmv, mu, alpha, beta, V,
-                                        fused_step=fused_step)
+                with jax.named_scope("fd.filter"):
+                    return chebyshev_filter(spmv, mu, alpha, beta, V,
+                                            fused_step=fused_step)
 
             self.filter = functools.partial(
                 jax.jit(run), self.spmv_panel, self.fused_step_panel)
@@ -435,7 +437,6 @@ class FilterDiag:
         cfg = self.cfg
         key = key if key is not None else jax.random.PRNGKey(cfg.seed)
         k0, k1 = jax.random.split(key)
-        t0 = time.perf_counter()
         lam = lanczos_interval(
             self.spmv_stack, self.D, self.D_pad, self.dtype, k0,
             cfg.lanczos_steps,
@@ -443,8 +444,7 @@ class FilterDiag:
                   else jnp.asarray(self.rowmap.valid_mask())),
         )
         V = self.random_search_vectors(k1)
-        return FDState(V=V, lam=lam, total_spmvs=cfg.lanczos_steps,
-                       wall_time=time.perf_counter() - t0)
+        return FDState(V=V, lam=lam, total_spmvs=cfg.lanczos_steps)
 
     def step_analyze(self, state: FDState, cfg: FDConfig | None = None,
                      verbose: bool = False) -> FDState:
@@ -455,95 +455,100 @@ class FilterDiag:
         ``cfg`` overrides the convergence-relevant fields (target, tol,
         n_target, …) — the service batcher passes per-request configs
         while sharing this solver's operators.
+
+        Spans (``core/spans.py``): ``step_analyze`` holding
+        ``orthogonalize``, ``ritz`` (its program and the fetch of θ and
+        the residuals) and ``choose_filter`` (host only).
         """
         cfg = cfg if cfg is not None else self.cfg
-        t_begin = time.perf_counter()
         it = state.iteration
-        if it >= cfg.max_iters:
-            # not converged within max_iters — report best effort
-            theta, Y, res, VY = self.ritz(self.orthogonalize(state.V))
-            theta_h, res_h = np.asarray(theta), np.asarray(res)
-            order = np.argsort(np.abs(theta_h - cfg.target))[: cfg.n_target]
-            state.wall_time += time.perf_counter() - t_begin
-            state.done = True
-            state.result = FDResult(
-                eigenvalues=theta_h[order], residuals=res_h[order],
-                n_converged=int((res_h[order] <= cfg.tol).sum()),
-                iterations=cfg.max_iters, total_spmvs=state.total_spmvs,
-                redistributions=state.redistributions,
-                wall_time=state.wall_time,
-                redist_time=state.redist_time, history=state.history,
-            )
-            return state
-        V = self.orthogonalize(state.V)
-        theta, Y, res, VY = self.ritz(V)
-        state.total_spmvs += cfg.n_search
-        theta_h = np.asarray(theta)
-        res_h = np.asarray(res)
-        target, search = self._intervals(theta_h, res_h, state.lam, cfg=cfg)
-        in_t = (theta_h >= target[0]) & (theta_h <= target[1])
-        conv = in_t & (res_h <= cfg.tol)
-        state.history.append(
-            dict(iter=it, n_conv=int(conv.sum()), search=search,
-                 best_res=float(res_h[in_t].min()) if in_t.any() else float("nan"))
-        )
-        if verbose:
-            print(f"[fd] it={it:3d} conv={int(conv.sum()):4d}/{cfg.n_target} "
-                  f"search=({search[0]:+.4e},{search[1]:+.4e}) "
-                  f"best_res={state.history[-1]['best_res']:.2e}")
-        if conv.sum() >= cfg.n_target:
-            order = np.argsort(np.abs(theta_h - cfg.target))
-            sel = order[conv[order]][: max(cfg.n_target, int(conv.sum()))]
-            state.wall_time += time.perf_counter() - t_begin
-            state.done = True
-            state.result = FDResult(
-                eigenvalues=theta_h[sel], residuals=res_h[sel],
-                n_converged=int(conv.sum()), iterations=it,
-                total_spmvs=state.total_spmvs,
-                redistributions=state.redistributions,
-                wall_time=state.wall_time,
-                redist_time=state.redist_time, history=state.history,
-            )
-            return state
-        poly = filters.build_filter(
-            search, state.lam, sharpness=cfg.sharpness,
-            n_max=cfg.degree_cap,
-        )
+        n_s = state.V.shape[1]
+        with span("step_analyze"):
+            with span("orthogonalize", columns=n_s) as s:
+                V = s.end_on(self.orthogonalize(state.V))
+            with span("ritz", columns=n_s, fetches=2):
+                theta, Y, res, VY = self.ritz(V)
+                theta_h = np.asarray(theta)
+                res_h = np.asarray(res)
+            if it >= cfg.max_iters:
+                # not converged within max_iters — report best effort
+                order = np.argsort(np.abs(theta_h - cfg.target))[: cfg.n_target]
+                return self._finish(state, theta_h[order], res_h[order],
+                                    int((res_h[order] <= cfg.tol).sum()),
+                                    cfg.max_iters)
+            state.total_spmvs += cfg.n_search
+            with span("choose_filter") as s:
+                target, search = self._intervals(theta_h, res_h, state.lam,
+                                                 cfg=cfg)
+                in_t = (theta_h >= target[0]) & (theta_h <= target[1])
+                conv = in_t & (res_h <= cfg.tol)
+                state.history.append(
+                    dict(iter=it, n_conv=int(conv.sum()), search=search,
+                         best_res=float(res_h[in_t].min()) if in_t.any() else float("nan"))
+                )
+                if verbose:
+                    print(f"[fd] it={it:3d} conv={int(conv.sum()):4d}/{cfg.n_target} "
+                          f"search=({search[0]:+.4e},{search[1]:+.4e}) "
+                          f"best_res={state.history[-1]['best_res']:.2e}")
+                if conv.sum() >= cfg.n_target:
+                    order = np.argsort(np.abs(theta_h - cfg.target))
+                    sel = order[conv[order]][: max(cfg.n_target, int(conv.sum()))]
+                    return self._finish(state, theta_h[sel], res_h[sel],
+                                        int(conv.sum()), it)
+                poly = filters.build_filter(
+                    search, state.lam, sharpness=cfg.sharpness,
+                    n_max=cfg.degree_cap,
+                )
+                s.counts["degree"] = poly.degree
         # start the filter from the Ritz basis (better conditioning)
         state.V = VY
         state.pending = (np.asarray(poly.mu), poly.degree)
-        state.wall_time += time.perf_counter() - t_begin
         return state
+
+    @staticmethod
+    def _finish(state: FDState, eigenvalues, residuals, n_converged: int,
+                iterations: int) -> FDState:
+        state.done = True
+        state.result = FDResult(
+            eigenvalues=eigenvalues, residuals=residuals,
+            n_converged=n_converged, iterations=iterations,
+            total_spmvs=state.total_spmvs,
+            redistributions=state.redistributions, history=state.history,
+        )
+        return state
+
+    def panel_filter(self, V, mu, alpha: float, beta: float) -> jax.Array:
+        """p(A) V for a stack-layout block ``V`` and host coefficients
+        ``mu`` ([deg+1], or [deg+1, N_s] per column): to the panel layout
+        (N_col > 1), the filter, back to the stack layout, each in a span
+        ``to_panel`` / ``filter`` / ``to_stack`` that ends on its output
+        while a trace records."""
+        if self.N_col > 1:
+            with span("to_panel", columns=V.shape[1]) as s:
+                V = s.end_on(self.to_panel(V))
+        with span("filter", degree=len(mu) - 1, columns=V.shape[1]) as s:
+            V = s.end_on(self.filter(V, jnp.asarray(mu), alpha, beta))
+        if self.N_col > 1:
+            with span("to_stack", columns=V.shape[1]) as s:
+                V = s.end_on(self.to_stack(V))
+        return V
 
     def step_filter(self, state: FDState,
                     cfg: FDConfig | None = None) -> FDState:
         """Second half of one outer iteration: apply the pending Chebyshev
         filter in the panel layout (redistributing if N_col > 1) and
-        advance the iteration counter."""
+        advance the iteration counter; the span ``step_filter`` holds
+        :meth:`panel_filter`'s."""
         cfg = cfg if cfg is not None else self.cfg
-        t_begin = time.perf_counter()
         mu_h, degree = state.pending
         alpha, beta = scale_params(*state.lam)
-        mu = jnp.asarray(mu_h)
-        V = state.V
-        t0 = time.perf_counter()
-        if self.N_col > 1:
-            V = self.to_panel(V)
-            jax.block_until_ready(V)
-            state.redistributions += 1
-            state.redist_time += time.perf_counter() - t0
-        V = self.filter(V, mu, alpha, beta)
+        with span("step_filter", degree=degree, columns=state.V.shape[1]):
+            state.V = self.panel_filter(state.V, mu_h, alpha, beta)
         state.total_spmvs += degree * cfg.n_search
-        t0 = time.perf_counter()
         if self.N_col > 1:
-            V = self.to_stack(V)
-            jax.block_until_ready(V)
-            state.redistributions += 1
-            state.redist_time += time.perf_counter() - t0
-        state.V = V
+            state.redistributions += 2
         state.pending = None
         state.iteration += 1
-        state.wall_time += time.perf_counter() - t_begin
         return state
 
     def step(self, state: FDState, verbose: bool = False) -> FDState:

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 from jax import shard_map
@@ -54,10 +55,13 @@ def make_tsqr(mesh: Mesh, layout: Layout):
     vec_spec = layout.vec_pspec()
 
     def local_fn(Vb):
-        Q0, R = _qr_fixed(Vb)  # local [R_loc, Ns] -> Q0 [R_loc, Ns], R [Ns, Ns]
-        acc = None
-        if levels:
+        with jax.named_scope("fd.local_qr"):
+            Q0, R = _qr_fixed(Vb)  # local [R_loc, Ns] -> Q0 [R_loc, Ns], R [Ns, Ns]
+        if not levels:
+            return Q0, R
+        with jax.named_scope("fd.butterfly"):
             idx = _flat_axis_index(mesh, dist)
+            acc = None
             for lvl in range(levels):
                 bit = 1 << lvl
                 perm = [(i, i ^ bit) for i in range(P_row)]
@@ -72,8 +76,7 @@ def make_tsqr(mesh: Mesh, layout: Layout):
                 mine = jnp.where(am_lo, 0, 1)
                 Qblk = lax.dynamic_slice_in_dim(Qf, mine * Ns, Ns, axis=0)  # [Ns, Ns]
                 acc = Qblk if acc is None else acc @ Qblk
-        Q = Q0 if acc is None else Q0 @ acc
-        return Q, R
+            return Q0 @ acc, R
 
     fn = shard_map(
         local_fn,

@@ -41,6 +41,7 @@ TPU — the tiled all_to_all already produces the canonical layout.
 """
 from __future__ import annotations
 
+import jax
 from jax import lax
 from jax import shard_map
 from jax.sharding import Mesh
@@ -74,10 +75,12 @@ def make_redistribute(mesh: Mesh, stack_layout: Layout, panel_layout: Layout,
         s_panel = panel_layout.vec_sharding(mesh)
 
         def to_panel(V):
-            return lax.with_sharding_constraint(V, s_panel)
+            with jax.named_scope("fd.to_panel"):
+                return lax.with_sharding_constraint(V, s_panel)
 
         def to_stack(V):
-            return lax.with_sharding_constraint(V, s_stack)
+            with jax.named_scope("fd.to_stack"):
+                return lax.with_sharding_constraint(V, s_stack)
 
         return to_panel, to_stack
 
@@ -89,11 +92,15 @@ def make_redistribute(mesh: Mesh, stack_layout: Layout, panel_layout: Layout,
 
     def _to_panel_local(Vb):
         # Vb: stack-local [D/P, N_s] -> panel-local [D/N_row, N_s/N_col]
-        return lax.all_to_all(Vb, col_axes, split_axis=1, concat_axis=0, tiled=True)
+        with jax.named_scope("fd.to_panel"):
+            return lax.all_to_all(Vb, col_axes, split_axis=1, concat_axis=0,
+                                  tiled=True)
 
     def _to_stack_local(Vb):
         # Vb: panel-local [D/N_row, N_s/N_col] -> stack-local [D/P, N_s]
-        return lax.all_to_all(Vb, col_axes, split_axis=0, concat_axis=1, tiled=True)
+        with jax.named_scope("fd.to_stack"):
+            return lax.all_to_all(Vb, col_axes, split_axis=0, concat_axis=1,
+                                  tiled=True)
 
     to_panel = shard_map(_to_panel_local, mesh=mesh, in_specs=(stack_spec,),
                          out_specs=panel_spec, check_vma=False)

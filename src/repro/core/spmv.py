@@ -786,7 +786,8 @@ def _ell_contract(acc, cols, vals, xsrc):
         c, v = cw
         return acc + v[:, None] * jnp.take(xsrc, c, axis=0), None
 
-    acc, _ = lax.scan(body, acc, (cols.T, vals.T))
+    with jax.named_scope("fd.spmv"):
+        acc, _ = lax.scan(body, acc, (cols.T, vals.T))
     return acc
 
 
@@ -801,8 +802,19 @@ def _contract_block(acc, cols, vals, xsrc, tiles):
     from ..kernels import ops as kops
 
     tile_cb, tcols, tvals, br, bc = tiles
-    return kops.ell_spmv_tiled(tile_cb, tcols, tvals, xsrc, y0=acc,
-                               br=br, bc=bc)
+    with jax.named_scope("fd.spmv"):
+        return kops.ell_spmv_tiled(tile_cb, tcols, tvals, xsrc, y0=acc,
+                                   br=br, bc=bc)
+
+
+def _halo_a2a(x, send_idx, dist_axes, P_row, L):
+    """The padded halo exchange: each shard's send slots to every peer in
+    one ``all_to_all``; returns the received [P_row * L, nb] halo."""
+    with jax.named_scope("fd.halo"):
+        send = jnp.take(x, send_idx, axis=0)  # [P, L, nb]
+        halo = lax.all_to_all(send, dist_axes, split_axis=0, concat_axis=0,
+                              tiled=False)
+        return halo.reshape(P_row * L, x.shape[1])
 
 
 def _local_spmv(cols, vals, send_idx, x, dist_axes, P_row, L, tiles=None):
@@ -814,9 +826,8 @@ def _local_spmv(cols, vals, send_idx, x, dist_axes, P_row, L, tiles=None):
     R, W = cols.shape
     nb = x.shape[1]
     if P_row > 1 and L:
-        send = jnp.take(x, send_idx, axis=0)  # [P, L, nb]
-        halo = lax.all_to_all(send, dist_axes, split_axis=0, concat_axis=0, tiled=False)
-        xfull = jnp.concatenate([x, halo.reshape(P_row * L, nb)], axis=0)
+        halo = _halo_a2a(x, send_idx, dist_axes, P_row, L)
+        xfull = jnp.concatenate([x, halo], axis=0)
     else:
         xfull = x
     acc0 = jnp.zeros((R, nb), dtype=jnp.result_type(vals.dtype, x.dtype))
@@ -837,9 +848,7 @@ def _local_spmv_overlap(cols_loc, vals_loc, cols_halo, vals_halo, send_idx, x,
     R = cols_loc.shape[0]
     nb = x.shape[1]
     if P_row > 1 and L:
-        send = jnp.take(x, send_idx, axis=0)  # [P, L, nb]
-        halo = lax.all_to_all(send, dist_axes, split_axis=0, concat_axis=0,
-                              tiled=False).reshape(P_row * L, nb)
+        halo = _halo_a2a(x, send_idx, dist_axes, P_row, L)
     else:
         halo = jnp.zeros((0, nb), dtype=x.dtype)
     acc0 = jnp.zeros((R, nb), dtype=jnp.result_type(vals_loc.dtype, x.dtype))
@@ -859,8 +868,9 @@ def _halo_parts_nbr(x, send_nbr, dist_axes, perms, round_L):
     parts = []
     off = 0
     for perm, Lk in zip(perms, round_L):
-        seg = jnp.take(x, send_nbr[off:off + Lk], axis=0)  # [Lk, nb]
-        parts.append(lax.ppermute(seg, dist_axes, perm=list(perm)))
+        with jax.named_scope("fd.halo"):
+            seg = jnp.take(x, send_nbr[off:off + Lk], axis=0)  # [Lk, nb]
+            parts.append(lax.ppermute(seg, dist_axes, perm=list(perm)))
         off += Lk
     return parts
 
@@ -1089,7 +1099,8 @@ def _build_engine(mesh: Mesh, layout: Layout, ell: DistEll, *, use_kernel: bool,
             dev = [a[0] for a in ins[:n_args]]
             w1, w2, a, b = ins[n_args:]
             y = body(w1, *dev)
-            return 2.0 * a * y + 2.0 * b * w1 - w2
+            with jax.named_scope("fd.cheb_update"):
+                return 2.0 * a * y + 2.0 * b * w1 - w2
 
         fn = shard_map(
             local_fn_fused,
@@ -1690,10 +1701,7 @@ def _build_sstep_group(mesh: Mesh, layout: Layout, sell: SstepEll, *,
                                          nbrp.perms, nbrp.round_L)
             else:
                 send_idx, gather = ex
-                buf = lax.all_to_all(
-                    jnp.take(payload, send_idx, axis=0), dist,
-                    split_axis=0, concat_axis=0, tiled=False,
-                ).reshape(sell.P * sell.L, payload.shape[1])
+                buf = _halo_a2a(payload, send_idx, dist, sell.P, sell.L)
 
         def take_ghosts():
             if has_halo:
@@ -1728,10 +1736,11 @@ def _build_sstep_group(mesh: Mesh, layout: Layout, sell: SstepEll, *,
                 ci, vi = later_arrs[2 * (i - 1)], later_arrs[2 * i - 1]
                 y = _ell_contract(jnp.zeros((R + G, nb), dtype=adt),
                                   ci, vi, w1e)
-            if first and i == 0:
-                t = a * y + b * w1e
-            else:
-                t = 2.0 * a * y + 2.0 * b * w1e - w2e
+            with jax.named_scope("fd.cheb_update"):
+                if first and i == 0:
+                    t = a * y + b * w1e
+                else:
+                    t = 2.0 * a * y + 2.0 * b * w1e - w2e
             ts.append(t[:R])
             w2e, w1e = w1e, t
         return jnp.stack(ts), w1e[:R], w2e[:R]

@@ -357,8 +357,7 @@ def main(argv=None):
                 machine=machine, plan_cache=args.plan_cache)
     print(f"converged {res.n_converged} eigenpairs in {res.iterations} "
           f"iterations / {res.total_spmvs} SpMVs "
-          f"({res.redistributions} redistributions, "
-          f"{100*res.redist_time/max(res.wall_time,1e-9):.1f}% redistribution time)")
+          f"({res.redistributions} redistributions)")
     print("eigenvalues:", np.array2string(res.eigenvalues, precision=10))
 
 

@@ -40,7 +40,6 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import time
 from typing import Any
 
 import numpy as np
@@ -52,6 +51,7 @@ from ..core import perf_model as pm
 from ..core.chebyshev import scale_params
 from ..core.filter_diag import FDConfig, FDResult, FDState, FilterDiag
 from ..core.lanczos import lanczos_interval
+from ..core.spans import span
 from ..core.planner import Candidate
 from ..runtime import StragglerWatchdog, Supervisor, SupervisorConfig
 from ..matrices import get_family
@@ -151,19 +151,16 @@ class BatchedJob:
         exactly the split a solo ``FilterDiag.init_state`` performs."""
         fd = self.fd
         k0 = jax.random.split(jax.random.PRNGKey(self.service_seed))[0]
-        t0 = time.perf_counter()
         lam = lanczos_interval(
             fd.spmv_stack, fd.D, fd.D_pad, fd.dtype, k0,
             fd.cfg.lanczos_steps,
             mask=(None if fd.rowmap is None
                   else jnp.asarray(fd.rowmap.valid_mask())),
         )
-        dt = time.perf_counter() - t0
         for e in self.entries:
             k1 = jax.random.split(jax.random.PRNGKey(e.cfg.seed))[1]
             e.state = FDState(V=fd.random_search_vectors(k1), lam=lam,
-                              total_spmvs=fd.cfg.lanczos_steps,
-                              wall_time=dt)
+                              total_spmvs=fd.cfg.lanczos_steps)
         return {e.req.req_id: e.state for e in self.entries}
 
     def step(self, states: dict) -> dict:
@@ -188,7 +185,8 @@ class BatchedJob:
 
     def _filter_batched(self, pend: list[_Entry]):
         """One shared Chebyshev sweep over the concatenated pending
-        blocks, per-column μ zero-padded to the longest degree."""
+        blocks, per-column μ zero-padded to the longest degree, in the
+        span ``batch_filter`` (``FilterDiag.panel_filter``'s spans inside)."""
         fd = self.fd
         lam = pend[0].state.lam
         alpha, beta = scale_params(*lam)
@@ -202,21 +200,10 @@ class BatchedJob:
             Mu[: deg + 1, col: col + w] = np.asarray(mu_h)[:, None]
             col += w
         V = jnp.concatenate([e.state.V for e in pend], axis=1)
-        t0 = time.perf_counter()
-        redist = 0
-        redist_time = 0.0
-        if fd.N_col > 1:
-            V = fd.to_panel(V)
-            jax.block_until_ready(V)
-            redist += 1
-            redist_time += time.perf_counter() - t0
-        V = fd.filter(V, jnp.asarray(Mu), alpha, beta)
-        t0 = time.perf_counter()
-        if fd.N_col > 1:
-            V = fd.to_stack(V)
-            jax.block_until_ready(V)
-            redist += 1
-            redist_time += time.perf_counter() - t0
+        with span("batch_filter", degree=n_max, columns=V.shape[1],
+                  requests=len(pend)):
+            V = fd.panel_filter(V, Mu, alpha, beta)
+        redist = 2 if fd.N_col > 1 else 0
         col = 0
         for e, w, deg in zip(pend, widths, degrees):
             st = e.state
@@ -227,7 +214,6 @@ class BatchedJob:
             # solo accounting: the request's own degree x its own width
             st.total_spmvs += deg * w
             st.redistributions += redist
-            st.redist_time += redist_time
 
     def done(self, states: dict) -> bool:
         return all(s.done for s in states.values())

@@ -65,7 +65,6 @@ def _result_to_json(r: FDResult | None):
         "n_converged": int(r.n_converged), "iterations": int(r.iterations),
         "total_spmvs": int(r.total_spmvs),
         "redistributions": int(r.redistributions),
-        "wall_time": float(r.wall_time), "redist_time": float(r.redist_time),
         "history": r.history,
     }
 
@@ -79,7 +78,6 @@ def _result_from_json(j) -> FDResult | None:
         n_converged=int(j["n_converged"]), iterations=int(j["iterations"]),
         total_spmvs=int(j["total_spmvs"]),
         redistributions=int(j["redistributions"]),
-        wall_time=float(j["wall_time"]), redist_time=float(j["redist_time"]),
         history=_history_from_json(j["history"]),
     )
 
@@ -98,8 +96,6 @@ def pack_state(state: FDState, fd: FilterDiag) -> tuple[dict, dict]:
         "iteration": int(state.iteration),
         "total_spmvs": int(state.total_spmvs),
         "redistributions": int(state.redistributions),
-        "redist_time": float(state.redist_time),
-        "wall_time": float(state.wall_time),
         "history": state.history,
         "done": bool(state.done),
         "result": _result_to_json(state.result),
@@ -110,7 +106,9 @@ def pack_state(state: FDState, fd: FilterDiag) -> tuple[dict, dict]:
 
 def unpack_state(tree: dict, extra: dict, fd: FilterDiag) -> FDState:
     """Rebuild an FDState from a restored (pytree, extra) pair, verifying
-    the solver's row decomposition matches the one checkpointed."""
+    the solver's row decomposition matches the one checkpointed. Keys the
+    state no longer has (older manifests' ``wall_time``/``redist_time``)
+    are ignored."""
     saved = extra.get("rowmap")
     here = rowmap_fingerprint(fd.rowmap)
     if saved != here:
@@ -122,8 +120,6 @@ def unpack_state(tree: dict, extra: dict, fd: FilterDiag) -> FDState:
         iteration=int(extra["iteration"]),
         total_spmvs=int(extra["total_spmvs"]),
         redistributions=int(extra["redistributions"]),
-        redist_time=float(extra["redist_time"]),
-        wall_time=float(extra["wall_time"]),
         history=_history_from_json(extra["history"]),
         done=bool(extra["done"]),
         result=_result_from_json(extra.get("result")),
